@@ -220,6 +220,9 @@ def main():
     add_metrics_flag(ap, "/tmp/repro_metrics/serve_bench.jsonl")
     add_server_flag(ap)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(smoke=args.smoke, model=args.model, requests=args.requests,
         max_batch=args.max_batch, out=args.out, metrics=args.metrics,
         metrics_port=args.metrics_port, openloop_rps=args.rate)

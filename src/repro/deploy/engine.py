@@ -23,7 +23,9 @@ so hot-swapping a package never invalidates the cache.
 ``data_parallel=True`` wraps the forward in a ``shard_map`` over the
 local devices' ``data`` axis (bucket sizes round up to a device
 multiple) — the single-host version of the production mesh in
-launch/mesh.py.
+launch/mesh.py.  The package is replicated onto every device once, at
+construction, and each microbatch is placed split over the axis before
+dispatch, so every device runs its own slice of the bucket.
 
 Accounting: every request records its latency SPLIT — ``queue_s``
 (enqueue -> bucket admit: the batch-formation share the ROADMAP calls
@@ -154,10 +156,17 @@ class SNNServeEngine:
         self._acct_lock = threading.Lock()
 
         self._mesh = None
+        self._batch_sharding = None
         n_dev = 1
         if ecfg.data_parallel:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from repro.launch.mesh import make_mesh
+
             n_dev = len(jax.devices())
-            self._mesh = jax.make_mesh((n_dev,), ("data",))
+            self._mesh = make_mesh((n_dev,), ("data",))
+            self._batch_sharding = NamedSharding(self._mesh, P("data"))
+            self.model = jax.device_put(model, NamedSharding(self._mesh, P()))
         self.buckets = ecfg.resolved_buckets(n_dev)
         self._fwd = self._build_forward()
         # bucket -> AOT-compiled executable; compiles happen exactly here
@@ -263,15 +272,14 @@ class SNNServeEngine:
 
         if self._mesh is None:
             return fwd
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
-        # model replicated, batch split over the data axis; check_rep off —
-        # the packed forward's pallas/interpret kernels confuse the
-        # replication checker on some jax versions
-        return shard_map(fwd, mesh=self._mesh,
-                         in_specs=(P(), P("data")),
-                         out_specs=P("data"), check_rep=False)
+        # model replicated, batch split over the data axis.  check_vma
+        # off: pallas_call declares no varying-axes rule, and every
+        # device's forward is independent anyway (no collectives)
+        return jax.shard_map(fwd, mesh=self._mesh,
+                             in_specs=(P(), P("data")),
+                             out_specs=P("data"), check_vma=False)
 
     def _executable(self, bucket: int):
         exe = self._compiled.get(bucket)
@@ -286,7 +294,7 @@ class SNNServeEngine:
                 cfg = self.cfg
                 spec = jax.ShapeDtypeStruct(
                     (bucket, cfg.img_size, cfg.img_size, cfg.in_channels),
-                    jnp.float32)
+                    jnp.float32, sharding=self._batch_sharding)
                 exe = jax.jit(self._fwd).lower(self.model, spec).compile()
                 self._compiled[bucket] = exe
                 self.compile_count += 1
@@ -377,7 +385,11 @@ class SNNServeEngine:
         # the annotation names this dispatch in --profile traces
         # (snn_serve_step/b<bucket>) — zero work when nothing is tracing
         with jax.profiler.TraceAnnotation(f"snn_serve_step/b{bucket}"):
-            logits = exe(self.model, jnp.asarray(images))
+            if self._batch_sharding is None:
+                images = jnp.asarray(images)
+            else:
+                images = jax.device_put(images, self._batch_sharding)
+            logits = exe(self.model, images)
         return InflightStep(batch=batch, bucket=bucket, n=n, logits=logits,
                             t0=t0, pad_frac=pad_frac)
 

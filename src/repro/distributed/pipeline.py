@@ -28,7 +28,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -59,10 +58,6 @@ def make_pipeline_loss(cfg: ArchConfig, mesh, n_micro: int,
 
     def inner(layers_shard, embed, final_norm_g, tokens, labels):
         # layers_shard: this stage's (L/stages, ...) params (shard_map view)
-        # NOTE: every scalar that crosses a scan/shard_map boundary below is
-        # carried as shape (1,): jax 0.4.x shard_map partial-eval promotes
-        # residuals to outputs named over the full mesh, and rank-0
-        # residuals fail its spec-rank check under jax.grad.
         stage = jax.lax.axis_index("pod")
         n_ticks = n_micro + n_stages - 1
         Bm = tokens.shape[0] // n_micro
@@ -100,8 +95,8 @@ def make_pipeline_loss(cfg: ArchConfig, mesh, n_micro: int,
             lse = jax.nn.logsumexp(logits, axis=-1)
             tgt = jnp.take_along_axis(
                 logits, jnp.clip(lb, 0)[..., None], axis=-1)[..., 0]
-            loss_sum = loss_sum + jnp.sum((lse - tgt) * mask)[None]
-            cnt_sum = cnt_sum + jnp.sum(mask)[None]
+            loss_sum = loss_sum + jnp.sum((lse - tgt) * mask)
+            cnt_sum = cnt_sum + jnp.sum(mask)
             # ship activations downstream (stage i -> i+1); ring closes
             # harmlessly (last->first arrivals are overwritten by x0)
             nxt = jax.lax.ppermute(
@@ -112,19 +107,19 @@ def make_pipeline_loss(cfg: ArchConfig, mesh, n_micro: int,
         recv0 = jnp.zeros((Bm, toks_m.shape[2], d), jnp.bfloat16
                           if cfg.dtype == "bfloat16" else jnp.float32)
         (recv, loss_sum, cnt_sum), _ = jax.lax.scan(
-            tick, (recv0, jnp.zeros((1,), jnp.float32),
-                   jnp.zeros((1,), jnp.float32)),
+            tick, (recv0, jnp.zeros((), jnp.float32),
+                   jnp.zeros((), jnp.float32)),
             jnp.arange(n_micro + n_stages - 1))
         # total loss lives on the last stage; share it
         axes = ("pod",) + ((data_axis,) if data_axis else ())
         loss_sum = jax.lax.psum(loss_sum, axes)
         cnt_sum = jax.lax.psum(cnt_sum, axes)
-        return (loss_sum / jnp.maximum(cnt_sum, 1.0))[0]
+        return loss_sum / jnp.maximum(cnt_sum, 1.0)
 
     bspec = P(data_axis) if data_axis else P()
 
     def loss_fn(params, batch):
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(
@@ -133,7 +128,7 @@ def make_pipeline_loss(cfg: ArchConfig, mesh, n_micro: int,
                 bspec, bspec,
             ),
             out_specs=P(),
-            check_rep=False,
+            check_vma=False,
         )(params["layers"], params["embed"],
           params["final_norm"]["g"] if "g" in params["final_norm"]
           else jnp.ones((cfg.d_model,)),

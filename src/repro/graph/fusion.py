@@ -15,7 +15,7 @@ this module is where such chains are proposed and policed:
     each chain capped by the computed VMEM budget.
   * :func:`validate_group` — the legality rules, with actionable errors:
     groups must be ≥2 contiguous conv/pool members, post-stem, stride 1,
-    entirely inside one region (all top-level, or exactly one residual
+    one kernel size, entirely inside one region (all top-level, or exactly one residual
     block's body — a chain cannot cross a residual boundary because the
     shortcut needs the pre-body plane), single-precision, pool-divisible,
     and within the per-core VMEM budget (kernels/vmem.py — the SAME
@@ -175,6 +175,14 @@ def validate_group(graph: ModelGraph, group: FusionGroup,
                 f"stride {spec.stride}: a stride change re-shapes the "
                 f"plane mid-chain; fuse up to the stride boundary and "
                 f"let the strided layer run its own fused_conv call")
+    ks = sorted({loc.spec.k for loc in located
+                 if isinstance(loc.spec, Conv)})
+    if len(ks) > 1:
+        raise ValueError(
+            f"fusion group {group.name!r} mixes kernel sizes {ks}: the "
+            f"fused kernel lays every member's plane out on one padded "
+            f"grid, so a chain runs one k; split the group where k "
+            f"changes")
     if not isinstance(located[0].spec, Conv):
         raise ValueError(
             f"fusion group {group.name!r} starts at pool "
@@ -238,8 +246,9 @@ def plan_fusion_groups(graph: ModelGraph,
                        budget: Optional[int] = None
                        ) -> Tuple[FusionGroup, ...]:
     """Propose legal fusion groups for ``graph``: maximal contiguous
-    chains of stride-1 post-stem Convs/Pools at the top level, plus each
-    all-stride-1 Residual body, every chain capped by the VMEM budget.
+    chains of stride-1 post-stem Convs/Pools of one kernel size at the
+    top level, plus each all-stride-1 Residual body, every chain capped
+    by the VMEM budget.
     Returns possibly-empty groups; every returned group passes
     :func:`validate_group`."""
     cap = budget if budget is not None else _vmem.vmem_budget_bytes()
@@ -262,7 +271,8 @@ def plan_fusion_groups(graph: ModelGraph,
         j = i + 1
         while j < len(nodes):
             nxt = nodes[j]
-            if isinstance(nxt, Conv) and not nxt.stem and nxt.stride == 1:
+            if isinstance(nxt, Conv) and not nxt.stem and nxt.stride == 1 \
+                    and nxt.k == node.k:
                 cand = members + [nxt.name]
             elif isinstance(nxt, Pool) and hw % nxt.window == 0 \
                     and hw >= nxt.window:
@@ -283,10 +293,11 @@ def plan_fusion_groups(graph: ModelGraph,
 
     # residual bodies: conv1 -> conv2 when the block entry is stride 1
     # (strided entries re-shape the plane inside conv1, which the chain
-    # contract excludes)
+    # contract excludes) and the body runs one kernel size
     for node in nodes:
         if isinstance(node, Residual) \
-                and all(c.stride == 1 for c in node.body):
+                and all(c.stride == 1 for c in node.body) \
+                and len({c.k for c in node.body}) == 1:
             members = tuple(c.name for c in node.body)
             if len(members) >= 2 and _fits(members):
                 proposals.append(members)

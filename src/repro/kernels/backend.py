@@ -32,11 +32,9 @@ def default_backend() -> str:
                 f"{_ENV_VAR}={env!r} is not a valid backend; "
                 f"expected one of {_VALID}")
         return env
-    try:
-        plat = jax.default_backend()
-    except Exception:  # pragma: no cover
-        plat = "cpu"
-    return "pallas" if plat == "tpu" else "jnp"
+    # a backend that fails to initialise raises here: silently serving the
+    # jnp reference would hide the device from every measurement
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 def get_backend() -> str:

@@ -2,50 +2,58 @@
 
 The fused_nce design extended to convolutions: all T timesteps of one
 spiking conv layer run in a single ``pallas_call`` with no intermediate
-HBM traffic.  Dataflow per (batch, c_out-tile) pair:
+HBM traffic.  Dataflow per batch element:
 
-    grid (B, N/bn, T), T innermost
+    grid (B, T), T innermost
     t-th step:
-      packed spike plane (1, 1, Hp, Wp*wc) --VPU shift/mask--> (Hp, Wp, Cp)
-      im2col gather: kh*kw strided slices -> patches (Ho*Wo, kh*kw*Cp)
-      packed weights (bn, K*bits/32)      --VPU shift/mask--> (bn, K) INTb
-      MXU:  i_syn = patches @ Wq^T        int8 x int8 -> int32
+      packed phase planes (S, cin_pad/32, Pq) --VPU shift/mask--> (cin_pad, Pq)
+      per tap: packed weight rows --VPU shift/mask--> (cin_pad, n) INTb
+               MXU: i_syn += Wtap^T . plane[:, off:off+L]   int8 -> int32
       VPU:  v -= v>>leak; v += i_syn; spike = v>=theta; reset
       VPU:  spike tile re-packed to 1-bit channel words, written to HBM
 
-The int32 membrane tile (Ho*Wo, bn) lives in a VMEM scratch buffer for
-the whole T-step scan (T is the innermost grid dim, so each (b, j) pair
-sees t = 0..T-1 consecutively and scratch persists).  Per timestep the
-only HBM traffic is one packed input spike plane (1 bit/event) and one
-packed output spike tile — the unfused float chain moves f32 currents
-and membranes through HBM at every step.
+Layout (kernels/fused_common.py): channels on rows, pixels on lanes.
+ops.py zero-pads the plane, splits it into ``stride**2`` phase planes
+(phase (a, c) holds padded pixels (stride*i + a, stride*j + c)) and
+flattens each over a grid of width ``wq``.  Output pixel (i, j) of tap
+(di, dj) then reads phase (di % s, dj % s) at lane
+``(i + di//s) * wq + j + dj//s``: every tap is one contiguous lane slice
+at a static offset, for any stride, and no strided slice or
+lane-splitting reshape reaches the compiler.  The kernel computes the
+(Ho, wq) output grid; the ``wq - Wo`` trailing columns of each row are
+junk that ops.py slices off.
 
-Weights stay resident per (b, j) pair across all T steps (index map
-constant in t), so each packed weight tile is fetched once per batch
-element, not once per timestep.
+The int32 membrane tile (n, Ho*wq) lives in a VMEM scratch buffer for
+the whole T-step scan (T is the innermost grid dim, so each b sees
+t = 0..T-1 consecutively and scratch persists).  Per timestep the only
+HBM traffic is one packed input plane (1 bit/event) and one packed
+output tile.  Weights stay resident per b across all T steps (index map
+constant in t).  The whole padded c_out is one block: at the paper's
+widths it fits VMEM, and a block that spans the array's last two dims
+meets the (8, 128) tiling rule for every c_out.
 
-Geometry contract (enforced by ops.py): the input plane arrives
-pre-padded (Hp = (Ho-1)*stride + kh, same for W), channels are packed to
-``cin_pad = 32*ceil(c_in/32)`` 1-bit fields, the flattened weight
-contraction uses the same per-tap cin_pad layout (quant.quantize_conv),
-and n (padded c_out) is a multiple of bn with bn % 32 == 0.  Zero-padded
-spike bits and zero weight codes are inert in the accumulate, and the
-``n_out`` mask zeroes spikes of padded output channels so the packed
-words match ``packing.pack_bool`` bit-for-bit.
+Geometry contract (enforced by ops.py): channels are packed to
+``cin_pad = 32*ceil(c_in/32)`` 1-bit fields, the weights are the
+QuantizedConvTensor words transposed to (kh*kw*cin_pad*bits/32, n) with
+the same per-tap cin_pad layout (quant.quantize_conv), and n (padded
+c_out) is a multiple of 32.  Zero-padded spike bits and zero weight
+codes are inert in the accumulate, and the ``n_out`` mask zeroes spikes
+of padded output channels so the packed words match
+``packing.pack_bool`` bit-for-bit.
 
 Spatial tiling (Ho blocks with halo DMA) is a follow-up — one batch
-element's plane must fit the per-tile VMEM budget, which holds for the
-paper's 32x32 CNN workloads.  That assumption is now an explicit check:
-the working set (kernels/vmem.py — the same formula the fusion planner
-budgets groups with) is validated against ``vmem_budget_bytes()`` and an
-oversized geometry raises ``ValueError`` here instead of emitting a
-kernel that cannot fit; ops.py pre-checks the same number and falls back
-to the unfused reference path, so model-level callers degrade gracefully.
+element's plane must fit the VMEM budget, which holds for the paper's
+32x32 CNN workloads.  The working set (kernels/vmem.py — the same
+formula the fusion planner budgets groups with) is checked against
+``vmem_budget_bytes()`` and an oversized geometry raises ``ValueError``
+here; ops.py pre-checks the same number and falls back to the unfused
+reference path with a ``RuntimeWarning``.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,88 +61,41 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
+from repro.kernels import fused_common as _fc
 from repro.kernels import vmem as _vmem
 
 
 def _fused_conv_kernel(s_ref, w_ref, th_ref, v_ref, o_ref, v_acc,
-                       *, bits: int, kh: int, kw: int, cin_pad: int,
-                       stride: int, ho: int, wo: int, n_out: int,
-                       leak_shift: int, v_reset_q: int,
-                       soft_reset: bool):
-    t = pl.program_id(2)
+                       *, bits: int, taps: Tuple, cin_pad: int,
+                       l_out: int, n_out: int, leak_shift: int,
+                       v_reset_q: int, soft_reset: bool):
+    t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
         v_acc[...] = jnp.zeros_like(v_acc)
 
-    # unpack this timestep's spike plane; packing.unpack is pure
-    # shift/mask jnp, so the shared helper traces inside the kernel and
-    # the bit layout can never diverge from the ref.py oracle's
-    s_words = s_ref[0, 0]                      # (Hp, Wp*wc)
-    hp = s_words.shape[0]
-    wp = (s_words.shape[1] * 32) // cin_pad
-    x = packing.unpack(s_words, 1, s_words.shape[1] * 32)
-    x = x.reshape(hp, wp, cin_pad).astype(jnp.int8)
-
-    # im2col gather: one strided slice per tap, concatenated in the
-    # (kh, kw, cin) order quantize_conv flattens the weight taps in
-    taps = []
-    for di in range(kh):
-        for dj in range(kw):
-            taps.append(jax.lax.slice(
-                x,
-                (di, dj, 0),
-                (di + (ho - 1) * stride + 1, dj + (wo - 1) * stride + 1,
-                 cin_pad),
-                (stride, stride, 1)))          # (Ho, Wo, cin_pad)
-    patches = jnp.concatenate(taps, axis=-1).reshape(ho * wo,
-                                                     kh * kw * cin_pad)
-
-    w_words = w_ref[...]                       # (bn, K*bits/32)
-    vpw_w = packing.WORD_BITS // bits
-    w = packing.unpack(w_words, bits,
-                       w_words.shape[-1] * vpw_w).astype(jnp.int8)
-
-    # binary x int accumulate on the MXU (multiplier-less in spirit: the
-    # left operand is {0,1}, every PE multiply is a masked pass-through)
-    i_syn = jax.lax.dot_general(
-        patches, w,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )                                          # (Ho*Wo, bn)
-
-    # shift-add LIF update on the VMEM-resident membrane tile.  theta is
-    # a per-output-channel row vector (the per-channel threshold fold);
-    # it broadcasts over the (Ho*Wo) pixel rows of the tile.
-    theta = th_ref[...]                        # (1, bn)
-    v = v_acc[...]
-    v = v - (v >> leak_shift) + i_syn
-    spikes = (v >= theta).astype(jnp.int32)
-    # zero spikes of zero-padded output channels so packed words are
-    # bit-identical to pack_bool of the unpadded reference
-    col = pl.program_id(1) * v.shape[1] + jax.lax.broadcasted_iota(
-        jnp.int32, v.shape, 1)
-    spikes = jnp.where(col < n_out, spikes, 0)
-    if soft_reset:
-        v = v - spikes * theta
-    else:
-        v = jnp.where(spikes == 1, jnp.int32(v_reset_q), v)
-
+    planes = {p: _fc.unpack_spikes(s_ref[0, 0, p], cin_pad)
+              for p in sorted({p for p, _ in taps})}
+    i_syn = _fc.conv_accumulate(planes, w_ref, taps, cin_pad, bits, l_out)
+    v, spikes = _fc.lif_fire(
+        v_acc[...], i_syn, th_ref[...], _fc.row_mask(i_syn.shape, n_out),
+        leak_shift=leak_shift, v_reset_q=v_reset_q, soft_reset=soft_reset)
     v_acc[...] = v
     v_ref[0] = v            # index map constant in t: written back once
-    o_ref[0, 0] = packing.pack_bool(spikes)  # bn % 32 == 0: no pad inserted
+    o_ref[0, 0] = packing.pack_bool(spikes, axis=-2)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("bits", "kh", "kw", "cin_pad", "stride", "ho", "wo",
-                     "n_out", "leak_shift", "v_reset_q",
-                     "soft_reset", "bn", "interpret"),
+                     "wq", "n_out", "leak_shift", "v_reset_q",
+                     "soft_reset", "interpret"),
 )
 def fused_conv_rollout_pallas(
-    spikes_packed_t: jnp.ndarray,  # (T, B, Hp, Wp*wc) int32, pre-padded
-    w_packed: jnp.ndarray,         # (n, kh*kw*cin_pad*bits/32) int32
-    theta_q: jnp.ndarray,          # (1, n) int32 per-channel thresholds
+    spikes_packed_t: jnp.ndarray,  # (T, B, stride**2, cin_pad/32, Pq) int32
+    w_packed_t: jnp.ndarray,       # (kh*kw*cin_pad*bits/32, n) int32
+    theta_q: jnp.ndarray,          # (n, 1) int32 per-channel thresholds
     *,
     bits: int,
     kh: int,
@@ -143,89 +104,87 @@ def fused_conv_rollout_pallas(
     stride: int,
     ho: int,
     wo: int,
+    wq: int,                       # width of the flattened phase grid
     n_out: int,                    # true c_out (<= n); masks padded channels
     leak_shift: int,
     v_reset_q: int = 0,
     soft_reset: bool = True,
-    bn: int = 128,
     interpret: bool = False,
 ):
-    t_steps, b, hp, wpw = spikes_packed_t.shape
-    n = w_packed.shape[0]
-    if cin_pad % 32 or (wpw * 32) % cin_pad:
+    t_steps, b, n_phase, wc, pq = spikes_packed_t.shape
+    kw_rows, n = w_packed_t.shape
+    if cin_pad % 32 or wc * 32 != cin_pad:
         raise ValueError(
-            f"cin_pad={cin_pad} must be a 32-multiple dividing the packed "
-            f"plane width {wpw * 32} (caller ops.py must pad channels)")
+            f"cin_pad={cin_pad} must be a 32-multiple matching the "
+            f"{wc} packed channel words (caller ops.py must pad channels)")
     k_flat = kh * kw * cin_pad
-    vpw_w = packing.WORD_BITS // bits
-    if w_packed.shape[1] * vpw_w != k_flat:
+    vpw_w = packing.values_per_word(bits)
+    if kw_rows * vpw_w != k_flat:
         raise ValueError(
             f"packed contraction mismatch: weights describe "
-            f"{w_packed.shape[1] * vpw_w}, im2col needs k={k_flat}")
-    if hp < (ho - 1) * stride + kh:
-        raise ValueError("input plane shorter than the gather footprint "
+            f"{kw_rows * vpw_w}, the taps need k={k_flat}")
+    if n_phase != stride * stride:
+        raise ValueError(f"stride {stride} needs {stride * stride} phase "
+                         f"planes, got {n_phase}")
+    taps = _fc.tap_offsets(kh, kw, stride, wq)
+    l_out = ho * wq
+    if wo > wq or max(off for _, off in taps) + l_out > pq:
+        raise ValueError("phase planes shorter than the gather footprint "
                          "(caller ops.py must pre-pad)")
-    if bn % 32 or n % bn:
-        raise ValueError("caller (ops.py) must pad c_out to bn multiples, "
-                         "bn % 32 == 0")
-    if theta_q.shape != (1, n):
+    if n % 32:
+        raise ValueError("caller (ops.py) must pad c_out to a 32-multiple")
+    if theta_q.shape != (n, 1):
         raise ValueError(
-            f"theta_q must be (1, {n}) per-channel thresholds, "
+            f"theta_q must be ({n}, 1) per-channel thresholds, "
             f"got {theta_q.shape} (caller ops.py must pad)")
     need = _vmem.conv_rollout_vmem_bytes(
-        hp=hp, wp=(wpw * 32) // cin_pad, cin_pad=cin_pad, kh=kh, kw=kw,
-        ho=ho, wo=wo, n=bn, bits=bits)
+        n_phase=n_phase, pq=pq, cin_pad=cin_pad, kh=kh, kw=kw,
+        l_out=l_out, n=n, bits=bits)
     budget = _vmem.vmem_budget_bytes()
     if need > budget:
         raise ValueError(
             f"fused_conv working set exceeds the per-core VMEM budget: "
             f"needs ~{_vmem.format_bytes(need)} > "
-            f"{_vmem.format_bytes(budget)} for plane "
-            f"{hp}x{(wpw * 32) // cin_pad}x{cin_pad} (padded), "
-            f"k={kh}x{kw}, out {ho}x{wo}, bn={bn}, w{bits} — the kernel "
-            f"would miscompile/spill rather than stay VMEM-resident.  "
-            f"Dispatch through fused_conv_ops to fall back to the "
-            f"unfused path, or raise REPRO_VMEM_BUDGET if your core has "
-            f"more VMEM.")
-    grid = (b, n // bn, t_steps)
+            f"{_vmem.format_bytes(budget)} for {n_phase} phase plane(s) "
+            f"of {pq} pixels x {cin_pad} channels, k={kh}x{kw}, out "
+            f"{ho}x{wo}, n={n}, w{bits}.  Dispatch through fused_conv_ops "
+            f"to fall back to the unfused path, or raise "
+            f"REPRO_VMEM_BUDGET if your core has more VMEM.")
     kernel = functools.partial(
         _fused_conv_kernel,
-        bits=bits, kh=kh, kw=kw, cin_pad=cin_pad, stride=stride,
-        ho=ho, wo=wo, n_out=n_out, leak_shift=leak_shift,
-        v_reset_q=v_reset_q, soft_reset=soft_reset,
+        bits=bits, taps=taps, cin_pad=cin_pad, l_out=l_out, n_out=n_out,
+        leak_shift=leak_shift, v_reset_q=v_reset_q, soft_reset=soft_reset,
     )
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b, t_steps),
         in_specs=[
-            pl.BlockSpec((1, 1, hp, wpw), lambda i, j, t: (t, i, 0, 0)),
-            pl.BlockSpec((bn, w_packed.shape[1]), lambda i, j, t: (j, 0)),
-            pl.BlockSpec((1, bn), lambda i, j, t: (0, j)),
+            pl.BlockSpec((1, 1, n_phase, wc, pq),
+                         lambda i, t: (t, i, 0, 0, 0)),
+            pl.BlockSpec((kw_rows, n), lambda i, t: (0, 0)),
+            pl.BlockSpec((n, 1), lambda i, t: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, ho * wo, bn), lambda i, j, t: (i, 0, j)),
-            pl.BlockSpec((1, 1, ho * wo, bn // 32),
-                         lambda i, j, t: (t, i, 0, j)),
+            pl.BlockSpec((1, n, l_out), lambda i, t: (i, 0, 0)),
+            pl.BlockSpec((1, 1, n // 32, l_out), lambda i, t: (t, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, ho * wo, n), jnp.int32),
-            jax.ShapeDtypeStruct((t_steps, b, ho * wo, n // 32), jnp.int32),
+            jax.ShapeDtypeStruct((b, n, l_out), jnp.int32),
+            jax.ShapeDtypeStruct((t_steps, b, n // 32, l_out), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((ho * wo, bn), jnp.int32)],
-        # batch and c_out tiles are independent; T carries the membrane
+        scratch_shapes=[pltpu.VMEM((n, l_out), jnp.int32)],
+        # batch elements are independent; T carries the membrane
         # recurrence through scratch and must stay sequential
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=2 * t_steps * b * ho * wo * k_flat * n,
             bytes_accessed=(
-                (n // bn) * spikes_packed_t.size * 4  # planes, per cout tile
-                + b * w_packed.size * 4               # weights, per b
-                + b * n * 4                           # theta, per b
-                + b * ho * wo * n * 4                 # membrane out
-                + t_steps * b * ho * wo * n // 8),    # spikes out
-
+                spikes_packed_t.size * 4              # planes
+                + w_packed_t.size * 4 + n * 4         # weights, theta
+                + b * l_out * n * 4                   # membrane out
+                + t_steps * b * l_out * n // 8),      # spikes out
             transcendentals=0,
         ),
         interpret=interpret,
-    )(spikes_packed_t, w_packed, theta_q)
+    )(spikes_packed_t, w_packed_t, theta_q)

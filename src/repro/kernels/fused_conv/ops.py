@@ -7,11 +7,13 @@ Dispatch rules (see repro.kernels.backend):
 
 The kernel path zero-pads the packed spike planes spatially (explicit
 SAME/VALID pads from ref.conv_pads — the exact amounts the oracle's XLA
-convolution uses), pads c_out to a ``bn`` tile multiple, flattens the
-(W, words) axes so the kernel sees one contiguous plane per batch
-element, then slices the padding back off.  Zero spike words are inert
-in the accumulate and the kernel masks spikes of padded channels, so
-padding never changes the visible bits.
+convolution uses), splits them into ``stride**2`` phase planes, moves
+the channel words onto rows and flattens the pixels onto lanes
+(``fused_common.phase_planes``), pads c_out to a 32-multiple, and transposes the
+packed weight words to (k*bits/32, c_out).  It then slices the junk grid
+columns and the padding back off (``fused_common.grid_to_nhwc``).  Zero spike
+words are inert in the accumulate and the kernel masks spikes of padded
+channels, so padding never changes the visible bits.
 
 Geometry too large for the kernel's VMEM working set (kernels/vmem.py —
 the single budget formula shared with the kernel's own check and the
@@ -30,6 +32,7 @@ import jax.numpy as jnp
 from repro.core import packing
 from repro.core.lif import as_theta_vector
 from repro.kernels import backend as _backend
+from repro.kernels import fused_common as _fc
 from repro.kernels import vmem as _vmem
 from repro.kernels.fused_conv import kernel as _kernel
 from repro.kernels.fused_conv import ref as _ref
@@ -50,7 +53,6 @@ def fused_conv_rollout(
     threshold_q: int,
     v_reset_q: int = 0,
     soft_reset: bool = True,
-    bn: int = 128,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """All T timesteps of one spiking conv layer in a single fused pass.
 
@@ -90,30 +92,21 @@ def fused_conv_rollout(
         return (jnp.zeros((b, ho, wo, qct.c_out), jnp.int32),
                 jnp.zeros((0, b, ho, wo, words_out), jnp.int32))
 
-    # pre-pad the packed planes: the gather footprint may run one short of
-    # the padded extent at the high edge (stride > 1), so extend to it
-    hp = max(h + plh + phh, (ho - 1) * stride + qct.kh)
-    wp = max(w + plw + phw, (wo - 1) * stride + qct.kw)
-    sp = jnp.pad(spikes_packed_t,
-                 ((0, 0), (0, 0), (plh, hp - h - plh),
-                  (plw, wp - w - plw), (0, 0)))
-    sp = sp.reshape(t_steps, b, hp, wp * win)
-
-    # one c_out tile if the layer is narrower than the default bn
-    bn_eff = min(bn, _round_up(qct.c_out, 32))
-    n_pad = _round_up(qct.c_out, bn_eff)
+    n_pad = _round_up(qct.c_out, 32)
+    sp, wq = _fc.phase_planes(spikes_packed_t, ((plh, phh), (plw, phw)),
+                          qct.kh, qct.kw, stride, ho, wo)
 
     # explicit VMEM residency check (the budget the fusion planner and
     # the kernel's own ValueError share): oversized geometry degrades to
     # the bit-exact unfused reference path instead of miscompiling
     need = _vmem.conv_rollout_vmem_bytes(
-        hp=hp, wp=wp, cin_pad=qct.c_in_pad, kh=qct.kh, kw=qct.kw,
-        ho=ho, wo=wo, n=bn_eff, bits=qct.bits)
+        n_phase=stride * stride, pq=sp.shape[-1], cin_pad=qct.c_in_pad,
+        kh=qct.kh, kw=qct.kw, l_out=ho * wq, n=n_pad, bits=qct.bits)
     budget = _vmem.vmem_budget_bytes()
     if need > budget:
         warnings.warn(
-            f"fused_conv geometry (plane {hp}x{wp}x{qct.c_in_pad} padded, "
-            f"out {ho}x{wo}, bn={bn_eff}, w{qct.bits}) needs "
+            f"fused_conv geometry (plane {h}x{w}x{qct.c_in_pad}, "
+            f"out {ho}x{wo}x{n_pad}, stride {stride}, w{qct.bits}) needs "
             f"~{_vmem.format_bytes(need)} of VMEM > budget "
             f"{_vmem.format_bytes(budget)}; falling back to the unfused "
             f"reference path (bit-exact, but per-timestep HBM traffic)",
@@ -123,19 +116,18 @@ def fused_conv_rollout(
             leak_shift=leak_shift, threshold_q=theta,
             v_reset_q=v_reset_q, soft_reset=soft_reset,
         )
-    wpk = jnp.pad(qct.data, ((0, n_pad - qct.c_out), (0, 0)))
+    wpk = jnp.pad(qct.data, ((0, n_pad - qct.c_out), (0, 0))).T
     # padded channels' theta value is irrelevant: their spikes are masked
     # by n_out inside the kernel before the reset uses theta
-    thp = jnp.pad(theta[None, :], ((0, 0), (0, n_pad - qct.c_out)))
+    thp = jnp.pad(theta[:, None], ((0, n_pad - qct.c_out), (0, 0)))
 
     v, out = _kernel.fused_conv_rollout_pallas(
         sp, wpk, thp,
         bits=qct.bits, kh=qct.kh, kw=qct.kw, cin_pad=qct.c_in_pad,
-        stride=stride, ho=ho, wo=wo, n_out=qct.c_out,
+        stride=stride, ho=ho, wo=wo, wq=wq, n_out=qct.c_out,
         leak_shift=leak_shift,
-        v_reset_q=v_reset_q, soft_reset=soft_reset, bn=bn_eff,
+        v_reset_q=v_reset_q, soft_reset=soft_reset,
         interpret=(_backend.get_backend() == "interpret"),
     )
-    v = v.reshape(b, ho, wo, n_pad)[..., :qct.c_out]
-    out = out.reshape(t_steps, b, ho, wo, n_pad // 32)[..., :words_out]
-    return v, out
+    return (_fc.grid_to_nhwc(v, ho, wq, wo, qct.c_out),
+            _fc.grid_to_nhwc(out, ho, wq, wo, words_out))

@@ -8,34 +8,42 @@ batch element:
 
     grid (B, T), T innermost
     t-th step:
-      packed input plane (1, 1, H, W*wc) --VPU shift/mask--> (H, W, Cp0)
+      packed input plane (cin_pad/32, Pq) --VPU shift/mask--> (cin_pad, Pq)
       for each member in the chain:
-        conv: pad SAME -> im2col (k*k strided slices) -> patches
-              packed weights --VPU unpack--> codes INTb
-              MXU: i_syn = patches @ Wq^T      int8 x int8 -> int32
+        conv: one accumulated MXU dot per tap (fused_common.conv_accumulate)
               VPU: LIF on this member's OWN VMEM membrane scratch
-              spike plane stays an int8 VMEM value -> next member's input
-        pool: non-overlapping window max (an OR for {0,1} planes)
+              spike plane stays a VMEM value -> next member's input
+        pool: window max over lane-shifted copies, then a one-hot
+              selection matmul that keeps the window corners and lays
+              them out as the next member's padded plane
       final plane re-packed to 1-bit channel words, written to HBM
 
-Each conv member keeps its int32 membrane tile (H_i*W_i, n_i) in its own
-VMEM scratch for the whole T-step scan (T is the innermost grid dim, so
-scratch persists across t).  Per timestep the only HBM traffic is ONE
-packed input plane and ONE packed output plane — the per-layer chain of
-fused_conv calls additionally writes + re-reads every intermediate
-member's packed planes through HBM each rollout.
+Layout (kernels/fused_common.py): channels on rows, pixels on lanes,
+every plane flattened row-major over a grid whose width is the next
+conv's padded width ``w + k - 1``.  A conv computes its (h, w + k - 1)
+output grid, so the ``k - 1`` junk columns of each row are exactly the
+right pad of that row and the left pad of the next once the plane is
+shifted by ``pad_lo`` rows and columns: masking them to zero and
+shifting is the whole re-pad between convs.  Pools change the grid, and
+the (static) one-hot operand built by ops.py does that gather on the
+MXU.
 
-Weights for every member stay resident per batch element (index maps
-constant in t), fetched once, exactly like fused_conv.
+Each conv member keeps its int32 membrane tile (n_i, h_i*wq_i) in its
+own VMEM scratch for the whole T-step scan (T is the innermost grid
+dim, so scratch persists across t).  Per timestep the only HBM traffic
+is ONE packed input plane and ONE packed output plane.  Weights for
+every member stay resident per batch element (index maps constant in
+t), fetched once, exactly like fused_conv.
 
 Geometry contract (enforced by ops.py): every conv is stride 1 with SAME
-padding (pad lo = (k-1)//2 — the exact amounts ref.conv_pads derives),
-channels chain 32-padded (member i's padded c_out IS member i+1's
-cin_pad; padded channels carry masked-to-zero spikes and zero weight
-codes, so they are inert), pools divide their plane exactly.  The whole
-working set must fit the shared VMEM budget (kernels/vmem.py — the same
-formula the fusion planner uses); oversized chains raise here and fall
-back to the per-layer reference in ops.py.
+padding (pad lo = (k-1)//2 — the exact amounts ref.conv_pads derives)
+and one kernel size k for the whole chain, channels chain 32-padded
+(member i's padded c_out IS member i+1's cin_pad; padded channels carry
+masked-to-zero spikes and zero weight codes, so they are inert), pools
+divide their plane exactly.  The whole working set must fit the shared
+VMEM budget (kernels/vmem.py — the same formula the fusion planner
+uses); oversized chains raise here and fall back to the per-layer
+reference in ops.py.
 """
 
 from __future__ import annotations
@@ -45,10 +53,12 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import packing
+from repro.kernels import fused_common as _fc
 from repro.kernels import vmem as _vmem
 
 # geom rows (static, hashable):
@@ -74,15 +84,60 @@ def _geom_vmem_dicts(geoms):
     return out
 
 
+def chain_grids(geoms) -> Tuple:
+    """Per member: (input grid width, output grid width, output grid
+    positions).  A conv's output grid is its padded width; a pool's is
+    the next conv's padded plane, or the compact pooled plane when no
+    conv follows."""
+    k = _conv_geoms(geoms)[0][2]
+    rows, grid_w = [], None
+    for i, g in enumerate(geoms):
+        if g[0] == "conv":
+            _, _, _, _, h, w, _, _ = g
+            wq = w + k - 1
+            rows.append((wq, wq, h * wq))
+            grid_w = wq
+        else:
+            _, p, h, w, _ = g
+            ho, wo = h // p, w // p
+            conv_next = i + 1 < len(geoms) and geoms[i + 1][0] == "conv"
+            w_out = wo + k - 1 if conv_next else wo
+            p_out = (ho + k) * w_out if conv_next else ho * wo
+            rows.append((grid_w, w_out, p_out))
+            grid_w = w_out
+    return tuple(rows)
+
+
+def pool_selection(geoms, index: int) -> np.ndarray:
+    """The one-hot (L_in, P_out) int8 operand of pool member ``index``:
+    column q picks the window corner that lands on grid position q of
+    the next member's (zero-padded) plane; pad positions stay zero."""
+    k = _conv_geoms(geoms)[0][2]
+    _, p, h, w, _ = geoms[index]
+    grid_in, w_out, p_out = chain_grids(geoms)[index]
+    conv_next = index + 1 < len(geoms) and geoms[index + 1][0] == "conv"
+    pad = (k - 1) // 2 if conv_next else 0
+    sel = np.zeros((h * grid_in, p_out), np.int8)
+    for i in range(h // p):
+        for j in range(w // p):
+            sel[p * i * grid_in + p * j, (i + pad) * w_out + j + pad] = 1
+    return sel
+
+
 def _fused_group_kernel(*refs, geoms, leak_shift: int, v_reset_q: int,
                         soft_reset: bool):
     convs = _conv_geoms(geoms)
     n_conv = len(convs)
+    n_pool = len(geoms) - n_conv
     s_ref = refs[0]
     w_refs = refs[1:1 + 2 * n_conv:2]
     th_refs = refs[2:2 + 2 * n_conv:2]
-    v_ref, o_ref = refs[1 + 2 * n_conv], refs[2 + 2 * n_conv]
-    v_accs = refs[3 + 2 * n_conv:]
+    sel_refs = refs[1 + 2 * n_conv:1 + 2 * n_conv + n_pool]
+    v_ref, o_ref = refs[1 + 2 * n_conv + n_pool:3 + 2 * n_conv + n_pool]
+    v_accs = refs[3 + 2 * n_conv + n_pool:]
+    grids = chain_grids(geoms)
+    k = convs[0][2]
+    pad_lo = (k - 1) // 2
 
     t = pl.program_id(1)
 
@@ -91,68 +146,65 @@ def _fused_group_kernel(*refs, geoms, leak_shift: int, v_reset_q: int,
         for acc in v_accs:
             acc[...] = jnp.zeros_like(acc)
 
-    # unpack the group's input plane (the one per-timestep HBM read)
-    _, _, _, cin_pad0, h0, w0, _, _ = convs[0]
-    s_words = s_ref[0, 0]                       # (H, W*wc)
-    x = packing.unpack(s_words, 1, s_words.shape[1] * 32)
-    x = x.reshape(h0, w0, cin_pad0).astype(jnp.int8)
-
-    ci = 0
+    # the group's input plane: the one per-timestep HBM read
+    x = _fc.unpack_spikes(s_ref[0, 0], convs[0][3])     # (cin_pad, Pq)
+    ci = pi = 0
     v_last = None
-    for g in geoms:
+    for gi, g in enumerate(geoms):
+        grid_in, grid_out, _ = grids[gi]
+        nxt = geoms[gi + 1][0] if gi + 1 < len(geoms) else None
         if g[0] == "conv":
-            _, bits, k, cin_pad, h, w, n_pad, n_out = g
-            pad_lo = (k - 1) // 2
-            pad_hi = k - 1 - pad_lo
-            xp = jnp.pad(x, ((pad_lo, pad_hi), (pad_lo, pad_hi), (0, 0)))
-            # im2col: one slice per tap, (kh, kw, cin) order — the same
-            # layout quantize_conv flattens the weight taps in
-            taps = []
-            for di in range(k):
-                for dj in range(k):
-                    taps.append(jax.lax.slice(
-                        xp, (di, dj, 0), (di + h, dj + w, cin_pad)))
-            patches = jnp.concatenate(taps, axis=-1).reshape(
-                h * w, k * k * cin_pad)
-
-            w_words = w_refs[ci][...]           # (n_pad, K*bits/32)
-            vpw = packing.WORD_BITS // bits
-            wq = packing.unpack(w_words, bits,
-                                w_words.shape[-1] * vpw).astype(jnp.int8)
-            i_syn = jax.lax.dot_general(
-                patches, wq,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            )                                   # (H*W, n_pad)
-
-            theta = th_refs[ci][...]            # (1, n_pad)
-            v = v_accs[ci][...]
-            v = v - (v >> leak_shift) + i_syn
-            spikes = (v >= theta).astype(jnp.int32)
-            # spikes of zero-padded output channels are masked so the
-            # next member (and the final pack) sees pack_bool-exact bits
-            col = jax.lax.broadcasted_iota(jnp.int32, spikes.shape, 1)
-            spikes = jnp.where(col < n_out, spikes, 0)
-            if soft_reset:
-                v = v - spikes * theta
-            else:
-                v = jnp.where(spikes == 1, jnp.int32(v_reset_q), v)
+            _, bits, _, cin_pad, h, w, n_pad, n_out = g
+            l_out = h * grid_out
+            i_syn = _fc.conv_accumulate([x], w_refs[ci],
+                                        _fc.tap_offsets(k, k, 1, grid_out),
+                                        cin_pad, bits, l_out)
+            valid = _fc.row_mask(i_syn.shape, n_out)
+            if nxt == "conv":
+                # junk grid columns become the next conv's zero padding
+                col = jax.lax.rem(
+                    jax.lax.broadcasted_iota(jnp.int32, i_syn.shape, 1),
+                    grid_out)
+                valid = valid & (col < w)
+            v, spikes = _fc.lif_fire(
+                v_accs[ci][...], i_syn, th_refs[ci][...], valid,
+                leak_shift=leak_shift, v_reset_q=v_reset_q,
+                soft_reset=soft_reset)
             v_accs[ci][...] = v
             v_last = v
-            # the inter-member handoff: a VMEM value, never an HBM write
-            x = spikes.reshape(h, w, n_pad).astype(jnp.int8)
+            x = spikes                                  # (n_pad, l_out)
+            if nxt == "conv":
+                lead = pad_lo * (grid_out + 1)
+                x = jnp.concatenate(
+                    [jnp.zeros((n_pad, lead), jnp.int32), x,
+                     jnp.zeros((n_pad, (h + k) * grid_out - lead - l_out),
+                               jnp.int32)], axis=1).astype(jnp.int8)
             ci += 1
         else:
-            _, window, h, w, c_pad = g
-            # non-overlapping window max == the binary-preserving OR
-            # pool maxpool_t applies between unfused layers
-            x = x.reshape(h // window, window, w // window, window,
-                          c_pad).max(axis=(1, 3))
+            _, p, h, w, c_pad = g
+            l_in = h * grid_in
+            xi = x.astype(jnp.int32)
+            xe = jnp.concatenate(
+                [xi, jnp.zeros((c_pad, (p - 1) * (grid_in + 1)), jnp.int32)],
+                axis=1)
+            # non-overlapping window max == the binary-preserving OR pool
+            # maxpool_t applies between unfused layers
+            m = xe[:, :l_in]
+            for a in range(p):
+                for b in range(p):
+                    if a or b:
+                        off = a * grid_in + b
+                        m = jnp.maximum(m, xe[:, off:off + l_in])
+            x = jax.lax.dot_general(
+                m.astype(jnp.int8), sel_refs[pi][...],
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32)       # (c_pad, p_out)
+            if nxt == "conv":
+                x = x.astype(jnp.int8)
+            pi += 1
 
-    hf, wf, cf = x.shape
     v_ref[0] = v_last           # last conv's membrane, constant-in-t map
-    o_ref[0, 0] = packing.pack_bool(
-        x.reshape(hf * wf, cf).astype(jnp.int32))
+    o_ref[0, 0] = packing.pack_bool(x.astype(jnp.int32), axis=-2)
 
 
 @functools.partial(
@@ -161,41 +213,24 @@ def _fused_group_kernel(*refs, geoms, leak_shift: int, v_reset_q: int,
                      "interpret"),
 )
 def fused_group_rollout_pallas(
-    spikes_packed_t: jnp.ndarray,   # (T, B, H, W*wc) int32, unpadded plane
-    *packed_operands: jnp.ndarray,  # per conv member: w_packed, theta_q
+    spikes_packed_t: jnp.ndarray,   # (T, B, cin_pad/32, Pq) int32 words
+    *packed_operands: jnp.ndarray,  # per conv: w_packed^T, theta; then
+                                    # one selection operand per pool
     geoms: Tuple,
     leak_shift: int,
     v_reset_q: int = 0,
     soft_reset: bool = True,
     interpret: bool = False,
 ):
-    t_steps, b, h_in, wcw = spikes_packed_t.shape
     convs = _conv_geoms(geoms)
-    if len(packed_operands) != 2 * len(convs):
-        raise ValueError(
-            f"{len(convs)} conv members need {2 * len(convs)} packed "
-            f"operands (w, theta per member), got {len(packed_operands)}")
-    _, bits0, _, cin_pad0, h0, w0, _, _ = convs[0]
     if geoms[0][0] != "conv":
         raise ValueError("a fusion group starts at a conv member")
-    if (h_in, wcw) != (h0, w0 * cin_pad0 // 32):
+    n_pool = len(geoms) - len(convs)
+    if len(packed_operands) != 2 * len(convs) + n_pool:
         raise ValueError(
-            f"input plane {h_in}x{wcw} words does not match the first "
-            f"member's geometry {h0}x{w0}x{cin_pad0} (caller ops.py "
-            f"flattens (W, words))")
-    for gi, g in enumerate(convs):
-        _, bits, k, cin_pad, h, w, n_pad, n_out = g
-        wp, th = packed_operands[2 * gi], packed_operands[2 * gi + 1]
-        vpw = packing.WORD_BITS // bits
-        if wp.shape != (n_pad, k * k * cin_pad * bits // 32):
-            raise ValueError(
-                f"member {gi}: packed weights {wp.shape} != "
-                f"({n_pad}, {k * k * cin_pad * bits // 32}) for geom {g}")
-        if th.shape != (1, n_pad):
-            raise ValueError(f"member {gi}: theta {th.shape} != (1, {n_pad})")
-        if n_pad % 32 or cin_pad % 32:
-            raise ValueError("caller ops.py must 32-pad channels")
-
+            f"{len(convs)} conv members and {n_pool} pools need "
+            f"{2 * len(convs) + n_pool} operands (w, theta per conv, one "
+            f"selection per pool), got {len(packed_operands)}")
     need = _vmem.group_rollout_vmem_bytes(_geom_vmem_dicts(geoms))
     budget = _vmem.vmem_budget_bytes()
     if need > budget:
@@ -206,57 +241,69 @@ def fused_group_rollout_pallas(
             f"through fused_group_ops (or the fusion planner) to split "
             f"or fall back instead of miscompiling.")
 
-    # final plane geometry (after any trailing pool)
-    hf, wf, cf = h0, w0, convs[0][6]
-    for g in geoms:
-        if g[0] == "conv":
-            hf, wf, cf = g[4], g[5], g[6]
-        else:
-            hf, wf = hf // g[1], wf // g[1]
+    t_steps, b, wc, pq = spikes_packed_t.shape
+    _, _, k, cin_pad0, h0, w0, _, _ = convs[0]
+    wq0 = w0 + k - 1
+    if wc * 32 != cin_pad0 or pq < (h0 + k - 1) * wq0 + k - 1:
+        raise ValueError(
+            f"input plane {wc}x{pq} words does not match the first "
+            f"member's padded geometry {h0}x{w0}x{cin_pad0}, k={k} "
+            f"(caller ops.py builds it with fused_common.phase_planes)")
+    for gi, g in enumerate(convs):
+        _, bits, kg, cin_pad, _, _, n_pad, _ = g
+        wp, th = packed_operands[2 * gi], packed_operands[2 * gi + 1]
+        if kg != k:
+            raise ValueError(f"member {gi}: k={kg} in a k={k} chain")
+        if wp.shape != (k * k * cin_pad * bits // 32, n_pad):
+            raise ValueError(
+                f"member {gi}: packed weights {wp.shape} != "
+                f"({k * k * cin_pad * bits // 32}, {n_pad}) for geom {g}")
+        if th.shape != (n_pad, 1):
+            raise ValueError(f"member {gi}: theta {th.shape} != ({n_pad}, 1)")
+        if n_pad % 32 or cin_pad % 32:
+            raise ValueError("caller ops.py must 32-pad channels")
+
+    grids = chain_grids(geoms)
     lc = convs[-1]
-    _, _, _, _, h_lc, w_lc, n_lc, _ = lc
+    n_lc, l_lc = lc[6], lc[4] * (lc[5] + k - 1)
+    cf, lf = (lc[6] if geoms[-1][0] == "conv" else geoms[-1][4]), grids[-1][2]
 
     kernel = functools.partial(
         _fused_group_kernel, geoms=geoms, leak_shift=leak_shift,
         v_reset_q=v_reset_q, soft_reset=soft_reset)
 
-    in_specs = [pl.BlockSpec((1, 1, h_in, wcw), lambda i, t: (t, i, 0, 0))]
-    for gi, g in enumerate(convs):
-        n_pad, kwords = g[6], g[2] * g[2] * g[3] * g[1] // 32
-        in_specs.append(
-            pl.BlockSpec((n_pad, kwords), lambda i, t: (0, 0)))
-        in_specs.append(pl.BlockSpec((1, n_pad), lambda i, t: (0, 0)))
+    const = lambda i, t: (0, 0)  # noqa: E731 — resident across b and t
+    in_specs = [pl.BlockSpec((1, 1, wc, pq), lambda i, t: (t, i, 0, 0))]
+    in_specs += [pl.BlockSpec(op.shape, const) for op in packed_operands]
 
     flops = sum(2 * t_steps * b * g[4] * g[5] * g[2] * g[2] * g[3] * g[6]
                 for g in convs)
-    w_bytes = sum(packed_operands[2 * gi].size * 4
-                  for gi in range(len(convs)))
+    op_bytes = sum(op.size * op.dtype.itemsize for op in packed_operands)
     return pl.pallas_call(
         kernel,
         grid=(b, t_steps),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, h_lc * w_lc, n_lc), lambda i, t: (i, 0, 0)),
-            pl.BlockSpec((1, 1, hf * wf, cf // 32),
-                         lambda i, t: (t, i, 0, 0)),
+            pl.BlockSpec((1, n_lc, l_lc), lambda i, t: (i, 0, 0)),
+            pl.BlockSpec((1, 1, cf // 32, lf), lambda i, t: (t, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h_lc * w_lc, n_lc), jnp.int32),
-            jax.ShapeDtypeStruct((t_steps, b, hf * wf, cf // 32),
-                                 jnp.int32),
+            jax.ShapeDtypeStruct((b, n_lc, l_lc), jnp.int32),
+            jax.ShapeDtypeStruct((t_steps, b, cf // 32, lf), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((g[4] * g[5], g[6]), jnp.int32)
-                        for g in convs],
+        scratch_shapes=[
+            pltpu.VMEM((g[6], g[4] * (g[5] + k - 1)), jnp.int32)
+            for g in convs],
         # batch elements are independent; T carries every member's
         # membrane recurrence through scratch and must stay sequential
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=flops,
             bytes_accessed=(spikes_packed_t.size * 4     # planes in
-                            + b * w_bytes                # weights, per b
-                            + b * h_lc * w_lc * n_lc * 4  # membrane out
-                            + t_steps * b * hf * wf * cf // 8),  # out
+                            + op_bytes                   # weights, selections
+                            + b * n_lc * l_lc * 4        # membrane out
+                            + t_steps * b * cf * lf // 8),  # spikes out
             transcendentals=0,
         ),
         interpret=interpret,

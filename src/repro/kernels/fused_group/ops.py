@@ -12,7 +12,7 @@ Member encoding (shared with ref.py and core.snn_layers):
 
 The chain contract this layer enforces before any kernel is built:
 at least two members, the first a conv, every conv stride-1 SAME with
-the same weight precision, channels threading exactly (member i's c_out
+the same weight precision and kernel size, channels threading exactly (member i's c_out
 is member i+1's c_in, pools preserving channels), and every pool
 dividing its plane.  Violations raise ValueError with the offending
 member — the graph-level planner (repro.graph.fusion) front-runs these
@@ -35,6 +35,7 @@ from repro.core import packing
 from repro.core.lif import as_theta_vector
 from repro.kernels import backend as _backend
 from repro.kernels import vmem as _vmem
+from repro.kernels import fused_common as _fc
 from repro.kernels.fused_group import kernel as _kernel
 from repro.kernels.fused_group import ref as _ref
 
@@ -92,6 +93,11 @@ def _normalize_members(members: Sequence[Tuple], h: int, w: int,
                 raise ValueError(
                     f"member {mi}: non-square kernel "
                     f"{qct.kh}x{qct.kw} is not fusable")
+            if norm and qct.kh != norm[0][1].kh:
+                raise ValueError(
+                    f"member {mi}: {qct.kh}x{qct.kh} kernel in a "
+                    f"{norm[0][1].kh}x{norm[0][1].kh} chain — one kernel "
+                    f"size per group (the planes share one padded grid)")
             norm.append(("conv", qct, as_theta_vector(theta, qct.c_out)))
             ch = qct.c_out
         elif m[0] == "pool":
@@ -181,24 +187,29 @@ def fused_group_rollout(
             spikes_packed_t, members, leak_shift=leak_shift,
             v_reset_q=v_reset_q, soft_reset=soft_reset)
 
+    k = convs[0][1].kh
+    pad = (k - 1) // 2
+    sp, _ = _fc.phase_planes(
+        spikes_packed_t, ((pad, k - 1 - pad), (pad, k - 1 - pad)),
+        k, k, 1, h, w)
     operands = []
     for _, qct, theta in convs:
         n_pad = _round32(qct.c_out)
         operands.append(jnp.pad(qct.data, ((0, n_pad - qct.c_out),
-                                           (0, 0))))
+                                           (0, 0))).T)
         # padded channels' theta is irrelevant: the kernel masks their
         # spikes by n_out before the reset uses theta
-        operands.append(jnp.pad(theta[None, :],
-                                ((0, 0), (0, n_pad - qct.c_out))))
+        operands.append(jnp.pad(theta[:, None],
+                                ((0, n_pad - qct.c_out), (0, 0))))
+    operands += [jnp.asarray(_kernel.pool_selection(geoms, i))
+                 for i, g in enumerate(geoms) if g[0] == "pool"]
 
-    sp = spikes_packed_t.reshape(t_steps, b, h, w * win)
     v, out = _kernel.fused_group_rollout_pallas(
-        sp, *operands, geoms=geoms, leak_shift=leak_shift,
+        sp[:, :, 0], *operands, geoms=geoms, leak_shift=leak_shift,
         v_reset_q=v_reset_q, soft_reset=soft_reset,
         interpret=(_backend.get_backend() == "interpret"))
 
-    n_lc = _round32(last_qct.c_out)
-    cf = _round32(last_qct.c_out)
-    v = v.reshape(b, h_lc, w_lc, n_lc)[..., :last_qct.c_out]
-    out = out.reshape(t_steps, b, hf, wf, cf // 32)[..., :words_out]
+    grid_f = _kernel.chain_grids(geoms)[-1][1]
+    v = _fc.grid_to_nhwc(v, h_lc, w_lc + k - 1, w_lc, last_qct.c_out)
+    out = _fc.grid_to_nhwc(out, hf, grid_f, wf, words_out)
     return v, out

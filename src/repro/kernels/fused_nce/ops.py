@@ -5,11 +5,12 @@ Dispatch rules (see repro.kernels.backend):
   'interpret' -> kernel.fused_nce_rollout_pallas(interpret=True)
   'pallas'    -> kernel.fused_nce_rollout_pallas (compiled, TPU)
 
-The kernel path pads batch to ``bm``, output neurons to ``bn`` and the
-packed contraction dim of both operands to a common k (multiple of 128),
-then slices the padding back off.  Zero spike words are inert in the
-accumulate and the kernel masks spikes of padded neurons, so padding
-never changes the visible bits.
+The kernel path pads output neurons to a 32-multiple and the packed
+contraction dim of both operands to a common k (multiple of 128), hands
+the kernel the transposed words (neurons and k on rows, batch on lanes —
+kernels/fused_common.py), then transposes and slices the padding back
+off.  Zero spike words are inert in the accumulate and the kernel masks
+spikes of padded neurons, so padding never changes the visible bits.
 """
 
 from __future__ import annotations
@@ -46,8 +47,6 @@ def fused_nce_rollout(
     threshold_q: int,
     v_reset_q: int = 0,
     soft_reset: bool = True,
-    bm: int = 8,
-    bn: int = 128,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """All T timesteps of one NCE layer in a single fused pass.
 
@@ -77,17 +76,16 @@ def fused_nce_rollout(
     vpw_w = packing.values_per_word(qt.bits)
     # common padded contraction dim: spike words to k/32, weight words to
     # k/vpw_w — padded spike words are zero, so the extra columns are inert
-    sp = _pad_axis(_pad_axis(spikes_packed_t, 1, bm), 2, _K_ALIGN // 32)
-    wp = _pad_axis(_pad_axis(qt.data, 0, bn), 1, _K_ALIGN // vpw_w)
+    sp = _pad_axis(spikes_packed_t, 2, _K_ALIGN // 32).transpose(0, 2, 1)
+    wp = _pad_axis(_pad_axis(qt.data, 0, 32), 1, _K_ALIGN // vpw_w).T
     # padded neurons' theta value is irrelevant: their spikes are masked
     # by n_out inside the kernel before the reset uses theta
-    thp = _pad_axis(theta[None, :], 1, bn)
+    thp = _pad_axis(theta[:, None], 0, 32)
     v, out = _kernel.fused_nce_rollout_pallas(
         sp, wp, thp,
         bits=qt.bits, n_out=n, leak_shift=leak_shift,
-        v_reset_q=v_reset_q,
-        soft_reset=soft_reset, bm=bm, bn=bn,
+        v_reset_q=v_reset_q, soft_reset=soft_reset,
         interpret=(be == "interpret"),
     )
     words_out = packing.packed_last_dim(n, 1)
-    return v[:b, :n], out[:, :b, :words_out]
+    return v[:n].T, out[:, :words_out].transpose(0, 2, 1)

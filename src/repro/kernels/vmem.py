@@ -3,27 +3,31 @@
 The fused kernels' entire win is VMEM residency: the membrane tile (and,
 for fusion groups, every inter-layer spike plane) lives in on-chip
 scratch for the whole T-step rollout.  That only works if the working
-set actually fits — a TPU core has ~16 MB of VMEM (see
-/opt/skills/guides), and a kernel whose scratch + operand blocks exceed
-it either fails to compile or silently spills.  Historically
-kernels/fused_conv assumed one batch element's plane fits ("fine at the
-paper's 32x32, broken beyond"); this module makes that assumption an
-explicit, shared number:
+set fits the kernel's scoped VMEM limit (16 MiB by default on a TPU
+v5e); a kernel whose buffers exceed it fails to compile.  This module
+makes the working set an explicit, shared number:
 
-  * :func:`conv_rollout_vmem_bytes` — the per-(batch, c_out-tile) VMEM
-    working set of one fused conv rollout, from static geometry alone.
+  * :func:`conv_rollout_vmem_bytes` — the per-batch-element VMEM working
+    set of one fused conv rollout (kernels/fused_conv), from static
+    geometry alone.
   * :func:`group_rollout_vmem_bytes` — the same for a multi-layer fusion
-    group (kernels/fused_group), where every member's membrane scratch
-    and the largest inter-layer plane are simultaneously resident.
+    group (kernels/fused_group), where every member's weights and
+    membrane scratch are resident at once.
   * :func:`vmem_budget_bytes` — the budget both the kernels (loud
     ``ValueError`` / unfused fallback) and the fusion planner
     (``repro.graph.fusion`` group legality) check against.  One number,
     one formula site: the planner can never admit a group the kernel
     would refuse.
 
-The default budget leaves headroom under the 16 MB core limit for the
-compiler's own double-buffering and semaphores; override with the
-``REPRO_VMEM_BUDGET`` env var (bytes) for experiments or tests.
+The formulas count the buffers of the kernels as written, including the
+double-buffered operand and result blocks the Pallas pipeline allocates,
+the int32 intermediates of the in-kernel unpack, and the lane padding of
+narrow blocks.  They overcount: compiled for a v5e, every paper-width
+kernel fits a scoped VMEM limit 2-6x below its estimate (the smallest
+``vmem_limit_bytes`` each compiles under), so the default budget, under
+the 16 MiB default limit, needs no ``vmem_limit_bytes`` in any kernel.
+Override the budget with the ``REPRO_VMEM_BUDGET`` env var (bytes) for
+experiments or tests.
 """
 
 from __future__ import annotations
@@ -31,12 +35,13 @@ from __future__ import annotations
 import os
 from typing import Dict, Sequence
 
-# ~16 MB/core on current TPU generations; budget 12 MB so the Mosaic
-# compiler keeps room for pipelining buffers and stack
+# default scoped VMEM limit of a Mosaic kernel on a TPU v5e core; the
+# budget keeps headroom under it for the compiler's internal scratch
 VMEM_BYTES_PER_CORE = 16 * 1024 * 1024
 DEFAULT_BUDGET_BYTES = 12 * 1024 * 1024
 
 _ENV_VAR = "REPRO_VMEM_BUDGET"
+_LANES = 128
 
 
 def vmem_budget_bytes() -> int:
@@ -53,40 +58,56 @@ def vmem_budget_bytes() -> int:
     return DEFAULT_BUDGET_BYTES
 
 
-def conv_rollout_vmem_bytes(*, hp: int, wp: int, cin_pad: int, kh: int,
-                            kw: int, ho: int, wo: int, n: int,
-                            bits: int) -> int:
-    """VMEM working set of one fused conv rollout step, per (batch,
-    c_out-tile) grid cell, from static geometry.
+def _lanes(x: int) -> int:
+    """Lane extent a buffer occupies: the last dim rounds up to 128."""
+    return -(-x // _LANES) * _LANES
 
-    ``hp``/``wp`` are the pre-padded plane dims, ``cin_pad`` the 32-padded
-    input channels, ``n`` the resident c_out extent (the tile size ``bn``
-    for the single-layer kernel, the full padded c_out for a fusion-group
-    member), ``bits`` the weight precision.  Counts every simultaneously
-    live buffer of kernels/fused_conv/kernel.py:
 
-      packed input plane block     hp * wp * cin_pad / 8      (int32 words)
-      unpacked spike plane         hp * wp * cin_pad          (int8)
-      im2col patches               ho * wo * kh*kw*cin_pad    (int8)
-      packed weight block          n * kh*kw*cin_pad * bits/8
-      unpacked weight codes        n * kh*kw*cin_pad          (int8)
-      i_syn + membrane scratch + v out block: 3 * ho*wo*n * 4 (int32)
-      theta row + packed out block (small, counted for completeness)
-    """
+def _rows(x: int) -> int:
+    """Sublane extent of an int32 buffer: rounds up to 8."""
+    return -(-x // 8) * 8
+
+
+def _conv_core_bytes(*, pq: int, cin_pad: int, kh: int, kw: int,
+                     l_out: int, n: int, bits: int) -> int:
+    """The buffers one conv layer keeps live while it computes, apart
+    from its input plane block: weights (double-buffered block), one
+    tap's unpacked weights, the tap slice, the int32 current, membrane
+    and spike tiles, and the theta / membrane / packed-spike blocks."""
     k_flat = kh * kw * cin_pad
-    return (hp * wp * cin_pad // 8          # packed plane block
-            + hp * wp * cin_pad             # unpacked plane (int8)
-            + ho * wo * k_flat              # im2col patches (int8)
-            + n * k_flat * bits // 8        # packed weights
-            + n * k_flat                    # unpacked weight codes (int8)
-            + 3 * ho * wo * n * 4           # i_syn + v scratch + v out
-            + n * 4                         # theta row
-            + ho * wo * (n // 32 or 1) * 4)  # packed out block
+    lo = _lanes(l_out)
+    return (2 * k_flat * bits // 8 * _lanes(n) // n   # packed weights x2
+            + 9 * cin_pad * _lanes(n)         # tap codes: int32 x2 + int8
+            + 2 * cin_pad * lo                # tap slice (int8) + copy
+            + 2 * _lanes(pq) * cin_pad * 5    # unpacked plane int32 + int8
+            + 8 * n * lo * 4                  # i_syn, dot, v, spikes,
+                                              # scratch, v out block x2
+            + 2 * n * _LANES * 4              # theta column block x2
+            + 2 * _rows(n // 32) * lo * 4)    # packed out block x2
+
+
+def conv_rollout_vmem_bytes(*, n_phase: int, pq: int, cin_pad: int,
+                            kh: int, kw: int, l_out: int, n: int,
+                            bits: int) -> int:
+    """VMEM working set of one fused conv rollout step, per batch
+    element, from static geometry.
+
+    ``n_phase`` phase planes of ``pq`` flattened pixels each arrive per
+    timestep (kernels/fused_common.phase_planes), ``cin_pad`` is the
+    32-padded input channel count, ``l_out`` the flattened output grid
+    (Ho * wq), ``n`` the 32-padded c_out and ``bits`` the weight
+    precision.
+    """
+    plane_block = 2 * n_phase * _rows(cin_pad // 32) * _lanes(pq) * 4
+    return (plane_block
+            + (n_phase - 1) * 2 * _lanes(pq) * cin_pad * 5
+            + _conv_core_bytes(pq=pq, cin_pad=cin_pad, kh=kh, kw=kw,
+                               l_out=l_out, n=n, bits=bits))
 
 
 def group_rollout_vmem_bytes(members: Sequence[Dict]) -> int:
     """VMEM working set of a multi-layer fusion-group rollout (one batch
-    element, all members' membranes resident at once).
+    element, all members' weights and membranes resident at once).
 
     ``members`` is a sequence of geometry dicts:
 
@@ -97,22 +118,40 @@ def group_rollout_vmem_bytes(members: Sequence[Dict]) -> int:
       {"kind": "pool", "h", "w", "c", "window"}
           c is the (padded) channel count of the pooled plane.
 
-    Conv members contribute their full single-layer working set with the
-    plane padded to h+kh-1 (every buffer is live while that member
-    computes, and its membrane scratch stays live for the whole group);
-    pool members contribute one plane buffer.  The sum is conservative —
-    buffers of *different* members mostly don't coexist except the
-    membrane scratches — which is the right direction for a budget.
+    Each conv member counts its full single-layer working set on its
+    padded (h + kh) x (w + kw - 1) grid; each pool counts its one-hot
+    selection operand (double-buffered int8) and its int32 window-max
+    and result planes.  The sum is conservative — buffers of different
+    members mostly do not coexist, apart from weights and membranes —
+    which is the right direction for a budget.
     """
     total = 0
-    for m in members:
+    grid_w = None               # width of the plane grid entering a member
+    for i, m in enumerate(members):
         if m["kind"] == "conv":
-            total += conv_rollout_vmem_bytes(
-                hp=m["h"] + m["kh"] - 1, wp=m["w"] + m["kw"] - 1,
-                cin_pad=m["cin_pad"], kh=m["kh"], kw=m["kw"],
-                ho=m["h"], wo=m["w"], n=m["n"], bits=m["bits"])
+            wq = m["w"] + m["kw"] - 1
+            pq = (m["h"] + m["kh"]) * wq
+            if i == 0:
+                total += 2 * _rows(m["cin_pad"] // 32) * _lanes(pq) * 4
+            total += _conv_core_bytes(
+                pq=pq, cin_pad=m["cin_pad"], kh=m["kh"], kw=m["kw"],
+                l_out=m["h"] * wq, n=m["n"], bits=m["bits"])
+            grid_w = wq
         elif m["kind"] == "pool":
-            total += m["h"] * m["w"] * m["c"]        # int8 plane
+            p = m["window"]
+            l_in = m["h"] * grid_w
+            l_max = l_in + (p - 1) * (grid_w + 1)
+            nxt = members[i + 1] if i + 1 < len(members) else None
+            ho, wo = m["h"] // p, m["w"] // p
+            if nxt is not None and nxt["kind"] == "conv":
+                grid_w = wo + nxt["kw"] - 1
+                p_out = (ho + nxt["kh"]) * grid_w
+            else:
+                grid_w = wo
+                p_out = ho * wo
+            total += (2 * _rows(l_in) * _lanes(p_out)       # selection x2
+                      + 2 * m["c"] * _lanes(l_max) * 4      # window max
+                      + m["c"] * _lanes(p_out) * 4)         # pooled plane
         else:
             raise ValueError(f"unknown member kind {m['kind']!r}")
     return total

@@ -351,8 +351,6 @@ def run_cell_cfg(cfg, arch: str, shape_name: str, *, tag_suffix: str = "",
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0] if ca else {}
         rec["flops_per_device"] = float(ca.get("flops", -1.0))
         rec["bytes_per_device"] = float(ca.get("bytes accessed", -1.0))
         ma = None

@@ -12,35 +12,35 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: the sharding
+    rules here (distributed/sharding.py, the serve engine's data axis)
+    place arrays with ``NamedSharding`` + ``jit`` and let the compiler
+    propagate.  jax's own default is explicit axes, which demand an
+    ``out_sharding`` on every ambiguous gather."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_abstract_mesh(shape, axes):
-    """Device-free mesh for static sharding-rule checks (axis sizes only).
-
-    Papers over the AbstractMesh constructor change: jax <= 0.4.x takes a
-    single ``((name, size), ...)`` pair tuple, newer jax takes
-    ``(sizes, names)`` like ``jax.make_mesh``.  Callers always pass
-    ``(sizes, names)``.
-    """
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:  # jax <= 0.4.x pair-tuple signature
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-free mesh for static sharding-rule checks (axis sizes only)."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Tiny mesh over the real local devices (CPU tests, laptop runs)."""
     n = len(jax.devices())
     mp = model_parallel if n % model_parallel == 0 else 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 def dp_axes(mesh) -> tuple:

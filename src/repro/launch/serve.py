@@ -16,6 +16,9 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--quant-bits", type=int, default=16)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import dataclasses
 

@@ -89,6 +89,9 @@ def main():
                          "alive S seconds after serving, for external "
                          "scrapes")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import time
 
@@ -168,9 +171,14 @@ def main():
                   f"silent={row['silent']:.3f} resets={row['resets']}")
         calibration = {row["layer"]: row["rate"] for row in layer_records}
 
+        from repro.perfmodel.roofline import V5E
+
+        # a CPU run predicts the v5e it stands in for; on a device the
+        # roofline is that device's own table entry (unknown kinds raise)
         _, timed_records = obs.timed_forward(
             cfg, model.float_params, sample, package=model,
-            registry=registry)
+            registry=registry,
+            peaks=V5E if jax.default_backend() == "cpu" else None)
         summ = obs.attribution_summary(timed_records)
         print(f"[obs] attribution: {summ['nodes']} nodes, "
               f"{summ['wall_us'] / 1e3:.1f}ms measured vs "

@@ -34,6 +34,9 @@ def main():
     add_metrics_flag(ap, "/tmp/repro_metrics/train.jsonl")
     add_server_flag(ap)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import dataclasses
 

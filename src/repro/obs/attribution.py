@@ -13,8 +13,11 @@ never inline on the serving path.
 Each node also gets an analytic prediction from the same first-principles
 model the committed ``benchmarks/BENCH_predicted.json`` rows are built
 with — the kernel ``CostEstimate`` formulas (packed-weight bytes +
-1-bit spike-plane traffic, MXU MACs) fed into the v5e roofline constants
-(``perfmodel.roofline.PEAK_FLOPS`` / ``HBM_BW``).  Every node emits:
+1-bit spike-plane traffic, MXU MACs) fed into the peaks of the chip the
+forward runs on (``perfmodel.roofline.PEAKS``, looked up by
+``device_kind``; integer layers against the int8 peak, the float stem
+against bf16).  A CPU run names the chip it predicts (``peaks=``).
+Every node emits:
 
   * ``snn_layer_time_us{layer=...,kind=...}`` — measured blocked wall
     time (gauge; the live /metrics series the acceptance criteria curl);
@@ -40,20 +43,20 @@ from repro.graph.executors import WrappedExecutor as _WrappedExecutor
 from repro.graph.spec import Conv, Dense, Residual
 from repro.obs.registry import MetricsRegistry, default_registry
 
-# v5e roofline constants — the SAME numbers perfmodel/roofline.py and
-# benchmarks/predicted_report.py use, so per-layer predictions sum to
-# the model-level rows already committed in BENCH_predicted.json
-from repro.perfmodel.roofline import HBM_BW, PEAK_FLOPS, pick_bottleneck
+from repro.perfmodel.roofline import (
+    V5E,
+    DevicePeaks,
+    device_peaks,
+    pick_bottleneck,
+)
 
 
-def predict_node_us(spec, timesteps: int, batch: int,
-                    bits: int) -> Optional[Dict]:
-    """Roofline prediction for one node's full T-step rollout at batch
-    ``batch``: compute term from MXU MACs (2 flops/MAC), memory term
-    from packed-weight bytes + 1-bit spike-plane traffic (the fused
-    kernels' CostEstimate accounting).  Returns ``None`` for nodes the
-    model has nothing to say about (encode / pool / readout)."""
+def _node_terms(spec, timesteps: int, batch: int, bits: int):
+    """(int8 flops, float flops, bytes) of one node's full T-step
+    rollout at batch ``batch``, or ``None`` for nodes the model has
+    nothing to say about (encode / pool / readout)."""
     T, B = timesteps, batch
+    int_path = bits <= 8                     # packed integer datapath
 
     def _conv_terms(c: Conv):
         w_bits = 32 if c.stem else bits      # stem stays on the float twin
@@ -62,31 +65,50 @@ def predict_node_us(spec, timesteps: int, batch: int,
         plane_bits = 32 if c.stem else 1     # analog currents in, else 1-bit
         act_bytes = T * B * (in_hw * in_hw * c.c_in * plane_bits
                              + c.out_hw * c.out_hw * c.c_out) / 8
-        return 2.0 * c.macs * T * B, weight_bytes + act_bytes
+        flops = 2.0 * c.macs * T * B
+        on_int = int_path and not c.stem
+        return (flops if on_int else 0.0, 0.0 if on_int else flops,
+                weight_bytes + act_bytes)
 
     if isinstance(spec, Conv):
-        flops, bytes_ = _conv_terms(spec)
-    elif isinstance(spec, Dense):
+        return _conv_terms(spec)
+    if isinstance(spec, Dense):
         flops = 2.0 * spec.macs * T * B
         bytes_ = spec.d_in * spec.d_out * bits / 8 \
             + T * B * (spec.d_in + spec.d_out) / 8
-    elif isinstance(spec, Residual):
-        flops, bytes_ = 0.0, 0.0
-        for c in (*spec.body, *((spec.proj,) if spec.proj else ())):
-            f, b = _conv_terms(c)
-            flops += f
-            bytes_ += b
-    else:
-        return None
-    t_comp, t_mem = flops / PEAK_FLOPS, bytes_ / HBM_BW
+        return (flops, 0.0, bytes_) if int_path else (0.0, flops, bytes_)
+    if isinstance(spec, Residual):
+        terms = [_conv_terms(c)
+                 for c in (*spec.body, *((spec.proj,) if spec.proj else ()))]
+        return tuple(sum(t[i] for t in terms) for i in range(3))
+    return None
+
+
+def _roofline(int_flops: float, float_flops: float, bytes_: float,
+              peaks: DevicePeaks) -> Dict:
+    t_comp = int_flops / peaks.int8_ops + float_flops / peaks.bf16_flops
+    t_mem = bytes_ / peaks.hbm_bw
     return {
         "predicted_us": round(max(t_comp, t_mem) * 1e6, 4),
         "compute_us": round(t_comp * 1e6, 4),
         "memory_us": round(t_mem * 1e6, 4),
         "bottleneck": pick_bottleneck(t_comp, t_mem, 0.0),
-        "flops": flops,
+        "flops": int_flops + float_flops,
         "bytes": bytes_,
     }
+
+
+def predict_node_us(spec, timesteps: int, batch: int, bits: int,
+                    peaks: DevicePeaks = V5E) -> Optional[Dict]:
+    """Roofline prediction for one node's full T-step rollout at batch
+    ``batch`` on a chip with ``peaks``: compute term from MXU MACs (2
+    ops/MAC; packed integer layers at the int8 peak, float layers at
+    bf16), memory term from packed-weight bytes + 1-bit spike-plane
+    traffic (the fused kernels' CostEstimate accounting).  Returns
+    ``None`` for nodes the model has nothing to say about (encode /
+    pool / readout)."""
+    terms = _node_terms(spec, timesteps, batch, bits)
+    return None if terms is None else _roofline(*terms, peaks)
 
 
 class AttributionExecutor(_WrappedExecutor):
@@ -97,8 +119,12 @@ class AttributionExecutor(_WrappedExecutor):
     kind = "attribution"
 
     def __init__(self, inner, registry: Optional[MetricsRegistry] = None,
-                 prefix: str = "snn_layer"):
+                 prefix: str = "snn_layer",
+                 peaks: Optional[DevicePeaks] = None):
         super().__init__(inner)
+        # the chip the forward runs on; unknown kinds raise
+        self.peaks = peaks if peaks is not None else device_peaks(
+            jax.devices()[0].device_kind)
         self.obs = registry if registry is not None else default_registry()
         self.prefix = prefix
         self.records: List[Dict] = []
@@ -139,28 +165,15 @@ class AttributionExecutor(_WrappedExecutor):
         cfg = self.inner.cfg
         bits = cfg.precision.bits if cfg.precision.quantized else 32
         if isinstance(spec_or_list, list):      # fusion group: sum members
-            total: Optional[Dict] = None
-            for s in spec_or_list:
-                p = predict_node_us(s, cfg.timesteps, self._batch, bits)
-                if p is None:
-                    continue
-                if total is None:
-                    total = dict(p)
-                else:
-                    for k in ("flops", "bytes"):
-                        total[k] += p[k]
-            if total is None:
+            terms = [t for t in (_node_terms(s, cfg.timesteps, self._batch,
+                                             bits) for s in spec_or_list)
+                     if t is not None]
+            if not terms:
                 return None
-            t_comp = total["flops"] / PEAK_FLOPS
-            t_mem = total["bytes"] / HBM_BW
-            total.update(
-                predicted_us=round(max(t_comp, t_mem) * 1e6, 4),
-                compute_us=round(t_comp * 1e6, 4),
-                memory_us=round(t_mem * 1e6, 4),
-                bottleneck=pick_bottleneck(t_comp, t_mem, 0.0))
-            return total
+            return _roofline(*(sum(t[i] for t in terms) for i in range(3)),
+                             self.peaks)
         return predict_node_us(spec_or_list, cfg.timesteps, self._batch,
-                               bits)
+                               bits, self.peaks)
 
     def _timed(self, kind: str, name: str, spec, fn):
         t0 = time.perf_counter()
@@ -191,18 +204,20 @@ class AttributionExecutor(_WrappedExecutor):
 
 
 def timed_forward(cfg, params, images, package=None,
-                  registry: Optional[MetricsRegistry] = None):
+                  registry: Optional[MetricsRegistry] = None,
+                  peaks: Optional[DevicePeaks] = None):
     """One eager TIMED forward of the model ``cfg`` describes — the
     attribution twin of ``instrumented_forward``: builds the graph,
     picks the float/int/packaged lowering, wraps it in
     :class:`AttributionExecutor`, runs it.  Returns ``(logits,
     records)`` and emits ``snn_layer_time_us`` + ``predicted_vs_measured``
-    into ``registry`` (default: the process default)."""
+    into ``registry`` (default: the process default).  ``peaks``: the
+    chip to predict (default: the live device's entry)."""
     from repro.graph import build_graph, executor_for, run_graph
 
     graph = build_graph(cfg)
     ex = AttributionExecutor(executor_for(graph, params, package=package),
-                             registry=registry)
+                             registry=registry, peaks=peaks)
     logits = run_graph(graph, ex, images)
     return logits, ex.records
 

@@ -16,8 +16,10 @@ Three instruments (methodology in EXPERIMENTS.md §Roofline):
 3. fit check — full-depth compile provides memory_analysis + proves the
    production mesh shards every cell (launch/dryrun.py).
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Hardware constants: :data:`PEAKS`, one table of published per-chip
+peaks keyed by ``jax.Device.device_kind``.  The offline LM roofline
+above models a TPU v5e (:data:`V5E`: 197 TFLOP/s bf16, 393 TOP/s int8,
+819 GB/s HBM, ~50 GB/s per ICI link).
 """
 
 from __future__ import annotations
@@ -27,9 +29,44 @@ import json
 from pathlib import Path
 from typing import Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published peaks of one accelerator chip."""
+
+    bf16_flops: float    # FLOP/s on the MXU in bf16
+    int8_ops: float      # OP/s on the MXU in int8
+    hbm_bw: float        # HBM bytes/s
+    link_bw: float       # bytes/s per chip-to-chip link
+    source: str
+
+
+V5E = DevicePeaks(
+    bf16_flops=197e12, int8_ops=393e12, hbm_bw=819e9,
+    link_bw=1600e9 / 8 / 4,             # 1,600 Gbit/s over 4 ICI links
+    source='Google Cloud documentation, "TPU v5e"')
+
+# keyed by jax's ``Device.device_kind``
+PEAKS = {"TPU v5 lite": V5E}
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The peaks of a live device.  A kind missing from :data:`PEAKS` is
+    an error, never a silent v5e stand-in: a roofline share against the
+    wrong chip's peak is a wrong number."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: "
+            f"{sorted(PEAKS)}); add it to perfmodel.roofline.PEAKS with "
+            f"its source") from None
+
+
+# the offline LM roofline's chip
+PEAK_FLOPS = V5E.bf16_flops
+HBM_BW = V5E.hbm_bw
+LINK_BW = V5E.link_bw
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results"
 

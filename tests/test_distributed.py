@@ -29,20 +29,20 @@ def run_py(code: str, devices: int = 8, timeout: int = 420) -> str:
 def test_ef_int8_allreduce_shard_map():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import ef_int8_allreduce_mean
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 128))
         e = jnp.zeros((8, 128))
 
         @jax.jit
         def step(g, e):
-            f = shard_map(
+            f = jax.shard_map(
                 lambda gi, ei: ef_int8_allreduce_mean(gi[0], ei[0], "data"),
                 mesh=mesh, in_specs=(P("data"), P("data")),
-                out_specs=P(None), check_rep=False)
+                out_specs=P(None), check_vma=False)
             return f(g, e)
 
         out, _ = step(g, e)
@@ -56,18 +56,19 @@ def test_ef_int8_allreduce_shard_map():
 def test_elastic_restore_across_meshes(tmp_path):
     print(run_py(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.distributed.checkpoint import CheckpointManager
 
         # save from a (4,2) mesh
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         sh_a = NamedSharding(mesh_a, P("data", "model"))
         w = jax.device_put(jnp.arange(64.0).reshape(8, 8), sh_a)
         cm = CheckpointManager({str(tmp_path)!r})
         cm.save(1, {{"w": w}})
 
         # restore onto a (2,4) mesh — elastic resharding on load
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((2, 4), ("data", "model"))
         sh_b = NamedSharding(mesh_b, P("data", "model"))
         out = cm.restore(1, {{"w": jax.eval_shape(lambda: w)}},
                          shardings={{"w": sh_b}})
@@ -83,6 +84,7 @@ def test_pjit_train_step_on_mesh():
     sharding rules — the single-host analogue of the pod dry-run."""
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.distributed import sharding as shd
@@ -91,7 +93,7 @@ def test_pjit_train_step_on_mesh():
         from repro.models.api import get_model
         from repro.train import optimizer as opt
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("olmo-1b", smoke=True)
         mb = get_model(cfg)
         params = mb.init(jax.random.PRNGKey(0))
@@ -133,6 +135,7 @@ def test_pipeline_parallel_matches_single_device():
     print(run_py("""
         import dataclasses
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import ArchConfig
         from repro.distributed.pipeline import (
             make_pipeline_loss, pipeline_param_specs)
@@ -150,7 +153,7 @@ def test_pipeline_parallel_matches_single_device():
 
         ref = float(T.loss_fn(params, cfg, batch))
 
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = make_mesh((2, 4), ("pod", "data"))
         pp_loss = make_pipeline_loss(cfg, mesh, n_micro=4)
         specs = pipeline_param_specs(params, mesh)
         sharded = jax.device_put(params, shd.to_shardings(specs, mesh))
@@ -171,6 +174,7 @@ def test_attention_cp_preserves_loss():
     same loss with and without the hint on a (data=2, model=4) mesh."""
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.models import layers as Ly
@@ -183,7 +187,7 @@ def test_attention_cp_preserves_loss():
         batch = {"tokens": toks, "labels": toks}
         ref = float(T.loss_fn(params, cfg, batch))
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         sh = NamedSharding(mesh, P("data", "model", None, None, None, None))
         Ly.set_attention_cp(
             hint=lambda x: jax.lax.with_sharding_constraint(x, sh),
@@ -199,3 +203,45 @@ def test_attention_cp_preserves_loss():
         assert abs(out - ref) / abs(ref) < 5e-3, (ref, out)
         print("attention-CP preserves loss")
     """, devices=8))
+
+
+def test_data_parallel_snn_engine_matches_single_device():
+    """The serve engine's shard_map data-parallel path on 4 devices:
+    buckets resolve to device multiples, every device computes its own
+    slice of a bucket, and the logits are bit-exact with one device."""
+    print(run_py("""
+        import time
+        import jax, numpy as np
+        from repro.deploy import (SNNEngineConfig, SNNRequest,
+                                  SNNServeEngine, deploy, deploy_config)
+        from repro.models import snn_cnn
+
+        cfg = deploy_config("vgg9", bits=4, smoke=True)
+        pkg = deploy(snn_cnn.init(jax.random.PRNGKey(0), cfg), cfg)
+        imgs = np.random.default_rng(0).random(
+            (6, cfg.img_size, cfg.img_size, cfg.in_channels), np.float32)
+
+        def serve(ecfg):
+            eng = SNNServeEngine(pkg, ecfg)
+            for i, im in enumerate(imgs):
+                eng.add_request(SNNRequest(uid=i, image=im))
+            eng.run_until_done()
+            return eng, np.stack([eng.pop_result(i).logits
+                                  for i in range(len(imgs))])
+
+        eng4, dp = serve(SNNEngineConfig(max_batch=8, data_parallel=True))
+        assert eng4.buckets == (4, 8), eng4.buckets
+        _, one = serve(SNNEngineConfig(max_batch=8, buckets=eng4.buckets))
+        np.testing.assert_array_equal(dp, one)
+
+        req = SNNRequest(uid=99, image=imgs[0])
+        req.queue_s, req._t0 = 0.0, time.perf_counter()
+        n_compiled = eng4.compile_count
+        st = eng4.begin_step([req], bucket=8)
+        shards = st.logits.addressable_shards
+        assert len({s.device for s in shards}) == 4, shards
+        assert all(s.data.shape[0] == 2 for s in shards), shards
+        eng4.finish_step(st)
+        assert eng4.compile_count == n_compiled
+        print("data-parallel engine OK")
+    """, devices=4))

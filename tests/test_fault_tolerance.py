@@ -126,7 +126,6 @@ def test_restart_protocol_gives_up():
 def test_ef_int8_compression_tracks_mean():
     """Compressed all-reduce over a 4-way axis: mean within int8 error and
     error-feedback shrinks the bias over repeated steps."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = jax.devices()

@@ -336,3 +336,37 @@ def test_fused_conv_roundtrip_property(bits, t_steps, h, w, cin, cout,
     u_k = packing.unpack_bool(s_k, cout)
     np.testing.assert_array_equal(
         np.asarray(packing.pack_bool(u_k)), np.asarray(s_k))
+
+
+@pytest.mark.parametrize("family", ["fused_conv", "fused_nce", "fused_group"])
+def test_pallas_call_builds_with_current_compiler_params(family):
+    """Each fused kernel's pallas_call entry builds and runs in interpret
+    mode with the installed jax's TPU compiler params (a renamed params
+    class broke every kernel on its first call, interpret included)."""
+    from repro.kernels.fused_conv import kernel as ck
+    from repro.kernels.fused_group import kernel as gk
+    from repro.kernels.fused_nce import kernel as nk
+
+    t, bits, n = 2, 4, 32
+    w = jnp.zeros((9 * 32 * bits // 32, n), jnp.int32)     # (K*bits/32, n)
+    th = jnp.full((n, 1), 8, jnp.int32)
+    if family == "fused_nce":
+        v, o = nk.fused_nce_rollout_pallas(
+            jnp.zeros((t, 4, 3), jnp.int32), w[:16], th, bits=bits,
+            n_out=n, leak_shift=3, interpret=True)
+        assert v.shape == (n, 3) and o.shape == (t, 1, 3)
+    elif family == "fused_conv":
+        # one 4x4 output plane, SAME 3x3, stride 1: a 6x6 padded grid
+        v, o = ck.fused_conv_rollout_pallas(
+            jnp.zeros((t, 1, 1, 1, 7 * 6), jnp.int32), w, th, bits=bits,
+            kh=3, kw=3, cin_pad=32, stride=1, ho=4, wo=4, wq=6, n_out=n,
+            leak_shift=3, interpret=True)
+        assert v.shape == (1, n, 4 * 6) and o.shape == (t, 1, 1, 4 * 6)
+    else:
+        geoms = (("conv", bits, 3, 32, 4, 4, n, n),
+                 ("conv", bits, 3, n, 4, 4, n, n))
+        v, o = gk.fused_group_rollout_pallas(
+            jnp.zeros((t, 1, 1, 7 * 6), jnp.int32), w, th, w, th,
+            geoms=geoms, leak_shift=3, interpret=True)
+        assert v.shape == (1, n, 4 * 6) and o.shape == (t, 1, 1, 4 * 6)
+    assert v.dtype == o.dtype == jnp.int32

@@ -4,7 +4,8 @@ The contract under test:
 
   * legality matrix — ``validate_group``/``apply_fusion`` reject every
     illegal chain (residual-boundary crossing, projection members,
-    strides, stems, non-contiguity, precision mixing, VMEM budget, ...)
+    strides, stems, non-contiguity, kernel-size and precision mixing,
+    VMEM budget, ...)
     with an actionable error, and ``plan_fusion_groups`` only proposes
     groups that pass the same rules;
   * bit-exactness — the multi-layer fused_group kernel matches the
@@ -22,6 +23,7 @@ The contract under test:
     formula with the planner.
 """
 
+import dataclasses
 import json
 
 import jax
@@ -40,7 +42,7 @@ from repro.graph import (
     plan_fusion_groups,
     validate_group,
 )
-from repro.graph.spec import FusionGroup, Residual
+from repro.graph.spec import Conv, FusionGroup, Residual
 from repro.kernels import fused_conv_ops, fused_group_ops, use_backend
 from repro.kernels import vmem as _vmem
 from repro.models import snn_cnn
@@ -161,6 +163,26 @@ def test_illegal_resnet18_groups(members, match):
     g = ungrouped_graph("resnet18")
     with pytest.raises(ValueError, match=match):
         validate_group(g, FusionGroup("bad", members))
+
+
+def _with_k(graph, name, k):
+    """``graph`` with conv ``name`` swapped for a k x k kernel."""
+    nodes = tuple(dataclasses.replace(n, k=k) if n.name == name else n
+                  for n in graph.nodes)
+    return dataclasses.replace(graph, nodes=nodes)
+
+
+def test_mixed_kernel_size_group_rejected_and_not_planned():
+    # the fused kernel lays every member out on one padded grid: a
+    # stride-1 1x1 conv may not join a 3x3 chain
+    g = _with_k(ungrouped_graph("vgg9"), "convs.3", 1)
+    with pytest.raises(ValueError, match="mixes kernel sizes"):
+        validate_group(g, FusionGroup("bad", ("convs.2", "convs.3")))
+    # ...and the planner stops the chain there instead of proposing it
+    for gr in plan_fusion_groups(g):
+        ks = {n.k for n in g.nodes if n.name in gr.members
+              and isinstance(n, Conv)}
+        assert len(ks) == 1, gr.members
 
 
 def test_precision_mixed_group_rejected():

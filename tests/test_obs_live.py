@@ -32,6 +32,7 @@ import pytest
 from repro import obs
 from repro.obs.registry import MetricsRegistry
 from repro.obs.watchdog import Watchdog, WatchdogConfig, histogram_quantile
+from repro.perfmodel.roofline import V5E
 
 
 def _get(url: str):
@@ -208,7 +209,8 @@ def test_attribution_records_and_metrics(deployed):
     ref = np.asarray(model.apply(images))
     reg = MetricsRegistry()
     logits, records = obs.timed_forward(cfg, model.float_params, images,
-                                        package=model, registry=reg)
+                                        package=model, registry=reg,
+                                        peaks=V5E)
     np.testing.assert_array_equal(np.asarray(logits), ref)
 
     from repro.graph import build_graph
@@ -240,16 +242,19 @@ def test_attribution_records_and_metrics(deployed):
 def test_predict_node_us_roofline_consistency():
     from repro.graph import build_graph
     from repro.obs.attribution import predict_node_us
-    from repro.perfmodel.roofline import HBM_BW, PEAK_FLOPS
-
     from repro.deploy import deploy_config
     cfg = deploy_config("vgg9", bits=4, smoke=True)
     graph = build_graph(cfg)
     convs = [n for n in graph.nodes if type(n).__name__ == "Conv"]
     p = predict_node_us(convs[1], cfg.timesteps, 2, 4)   # non-stem conv
-    # predicted_us is rounded to 4 decimals at emission
+    # predicted_us is rounded to 4 decimals at emission; a packed
+    # integer layer runs at the int8 peak
     assert p["predicted_us"] == pytest.approx(
-        max(p["flops"] / PEAK_FLOPS, p["bytes"] / HBM_BW) * 1e6, abs=1e-4)
+        max(p["flops"] / V5E.int8_ops, p["bytes"] / V5E.hbm_bw) * 1e6,
+        abs=1e-4)
+    stem = predict_node_us(convs[0], cfg.timesteps, 2, 4)  # float stem
+    assert stem["compute_us"] == pytest.approx(
+        stem["flops"] / V5E.bf16_flops * 1e6, abs=1e-4)
     assert p["predicted_us"] == max(p["compute_us"], p["memory_us"])
     # more timesteps -> strictly more predicted work
     p2 = predict_node_us(convs[1], cfg.timesteps * 2, 2, 4)

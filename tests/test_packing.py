@@ -51,6 +51,26 @@ def test_numpy_and_jax_packing_bit_identical(bits, n, seed):
     np.testing.assert_array_equal(w_np, w_jx)
 
 
+@pytest.mark.parametrize("bits", [1, 2, 4, 8])
+@pytest.mark.parametrize("n", [1, 33, 64])
+def test_row_axis_is_the_transposed_lane_layout(bits, n):
+    """axis=-2 (the fused kernels' on-chip form) packs the same fields as
+    the last-axis layout stored in HBM, only transposed."""
+    lo = 0 if bits == 1 else -(1 << (bits - 1))
+    hi = 2 if bits == 1 else (1 << (bits - 1))
+    v = jax.random.randint(jax.random.PRNGKey(n + bits), (2, n, 5), lo, hi,
+                           jnp.int32)
+    w = packing.pack(v, bits, axis=-2)
+    assert w.shape == (2, packing.packed_last_dim(n, bits), 5)
+    np.testing.assert_array_equal(
+        np.asarray(w),
+        np.asarray(packing.pack(v.swapaxes(-1, -2), bits).swapaxes(-1, -2)))
+    np.testing.assert_array_equal(
+        np.asarray(packing.unpack(w, bits, n, axis=-2)), np.asarray(v))
+    with pytest.raises(ValueError, match="axis"):
+        packing.pack(v, bits, axis=0)
+
+
 def test_compression_density():
     # 16x INT2 per int32 word — the SIMD payload the paper packs
     for bits, vpw in ((2, 16), (4, 8), (8, 4), (1, 32)):

@@ -114,3 +114,23 @@ def test_walker_collectives_empty_on_single_device():
     out = analyze_hlo(hlo)
     assert out["collectives"].get("total", 0) == 0
     assert out["hbm_bytes"] > 0
+
+
+def test_device_peaks_keyed_by_device_kind():
+    """One peak table keyed by jax's device_kind: v5e carries its
+    published bf16/int8/HBM peaks, and an unknown kind is an error, not a
+    silent v5e stand-in (attribution on an unlisted device raises)."""
+    from repro.obs.attribution import AttributionExecutor
+    from repro.perfmodel import roofline
+
+    v5e = roofline.device_peaks("TPU v5 lite")
+    assert v5e is roofline.V5E
+    assert (v5e.bf16_flops, v5e.int8_ops, v5e.hbm_bw) == (197e12, 393e12,
+                                                          819e9)
+    assert "TPU v5e" in v5e.source
+    assert roofline.PEAK_FLOPS == v5e.bf16_flops
+    assert roofline.HBM_BW == v5e.hbm_bw
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.device_peaks("cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        AttributionExecutor(inner=None)      # the CPU host is not listed
