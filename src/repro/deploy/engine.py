@@ -49,18 +49,30 @@ Observability: the engine binds instruments from a
 :class:`repro.obs.MetricsRegistry` (the process default unless one is
 passed) at construction — request/batch/compile-hit/miss counters,
 queue-depth / batch-occupancy / padding-waste gauges, queue/compute/
-latency histograms, and span events for enqueue -> admit -> compile ->
-step -> drain.  With the default registry disabled (the default) every
+latency histograms, and per-request ``enqueue`` / ``drain`` span
+events.  With the default registry disabled (the default) every
 instrument is a shared no-op, so the serving hot path pays only empty
-method calls — the bench-gate serve baseline holds either way.  Each
-device step also runs under a ``jax.profiler.TraceAnnotation`` named by
-bucket, so ``--profile`` traces read as ``snn_serve_step/b<bucket>``
-instead of anonymous dispatches.
+method calls — the bench-gate serve baseline holds either way.
+
+The worker's phases are recorded whatever the registry's state: one
+:class:`repro.obs.PhaseSpan` per phase per forward, carrying the id of
+the cohort that caused it (``new_cohort``, drawn when a take yields a
+cohort; see ``MetricsRegistry.phase``).  In loop order they follow one
+another without overlap: ``take`` (the caller's pop from its queue),
+``fill`` (seating the cohort and filling the bucket array), ``compile``
+(a bucket-executable miss only), ``dispatch`` (host->device transfer
+plus the executable call), ``wait`` (``block_until_ready``),
+``readback`` (logits to the host) and ``resolve`` (accounting and the
+per-request sinks).  Every site opens its phase with
+:meth:`SNNServeEngine.phase`, which also runs the block under a
+``jax.profiler.TraceAnnotation`` (:func:`annotation`), so a
+``--profile`` trace names the host's phase on the device's clock.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import threading
 import time
@@ -73,6 +85,46 @@ import numpy as np
 
 from repro import obs
 from repro.deploy.package import DeployedModel
+
+ANNOTATION_PREFIX = "snn_serve_step/"
+
+
+def annotation(phase: str, bucket: int = 0) -> str:
+    """Profiler annotation of a worker phase: ``snn_serve_step/<phase>``;
+    a dispatch keeps the name device traces have always shown,
+    ``snn_serve_step/b<bucket>``, and a compile is
+    ``snn_serve_step/compile/b<bucket>``."""
+    if phase == "dispatch":
+        return f"{ANNOTATION_PREFIX}b{bucket}"
+    if phase == "compile":
+        return f"{ANNOTATION_PREFIX}compile/b{bucket}"
+    return ANNOTATION_PREFIX + phase
+
+
+class Phase:
+    """One worker phase as a context manager: its block runs under the
+    profiler annotation ``name`` and, on exit, lands in ``registry``'s
+    phase ring.  ``cohort``, ``bucket`` and ``n`` may be set inside the
+    block, once they are known."""
+
+    __slots__ = ("_registry", "_phase", "_name", "_ann", "t0", "cohort",
+                 "bucket", "n")
+
+    def __init__(self, registry, phase: str, name: str, cohort: int = -1,
+                 bucket: int = 0, n: int = 0):
+        self._registry, self._phase, self._name = registry, phase, name
+        self.cohort, self.bucket, self.n = cohort, bucket, n
+
+    def __enter__(self) -> "Phase":
+        self.t0 = time.perf_counter()
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        self._registry.phase(self._phase, self.t0, time.perf_counter(),
+                             self.cohort, self.bucket, self.n)
 
 
 @dataclasses.dataclass
@@ -105,7 +157,7 @@ class InflightStep:
     n: int
     logits: object                  # un-materialized device array
     t0: float                       # perf_counter at dispatch
-    pad_frac: float
+    cohort: int                     # id of the phase spans it caused
 
 
 @dataclasses.dataclass
@@ -168,6 +220,10 @@ class SNNServeEngine:
             self._batch_sharding = NamedSharding(self._mesh, P("data"))
             self.model = jax.device_put(model, NamedSharding(self._mesh, P()))
         self.buckets = ecfg.resolved_buckets(n_dev)
+        # (phase, bucket) -> annotation name, built once per bucket
+        self._annotations = {(p, b): annotation(p, b)
+                             for p in obs.PHASES for b in (0,) + self.buckets}
+        self._cohorts = itertools.count()
         self._fwd = self._build_forward()
         # bucket -> AOT-compiled executable; compiles happen exactly here
         self._compiled: Dict[int, jax.stages.Compiled] = {}
@@ -281,7 +337,23 @@ class SNNServeEngine:
                              in_specs=(P(), P("data")),
                              out_specs=P("data"), check_vma=False)
 
-    def _executable(self, bucket: int):
+    def phase(self, phase: str, cohort: int = -1, bucket: int = 0,
+              n: int = 0) -> Phase:
+        """Open worker phase ``phase`` (one of ``obs.PHASES``) of cohort
+        ``cohort``: a :class:`Phase` that runs its block under the
+        phase's annotation and records the span into this engine's
+        registry on exit."""
+        name = self._annotations.get((phase, bucket))
+        if name is None:                # a bucket the config does not list
+            name = self._annotations[phase, bucket] = annotation(phase,
+                                                                 bucket)
+        return Phase(self.obs, phase, name, cohort, bucket, n)
+
+    def new_cohort(self) -> int:
+        """A fresh cohort id, the span that caused a forward's phases."""
+        return next(self._cohorts)
+
+    def _executable(self, bucket: int, cohort: int = -1, n: int = 0):
         exe = self._compiled.get(bucket)
         if exe is None:
             with self._compile_lock:     # concurrent workers build once
@@ -290,16 +362,16 @@ class SNNServeEngine:
                     self._m_compile_hit.inc()
                     return exe
                 self._m_compile_miss.inc()
-                t0 = time.perf_counter()
                 cfg = self.cfg
-                spec = jax.ShapeDtypeStruct(
-                    (bucket, cfg.img_size, cfg.img_size, cfg.in_channels),
-                    jnp.float32, sharding=self._batch_sharding)
-                exe = jax.jit(self._fwd).lower(self.model, spec).compile()
+                with self.phase("compile", cohort, bucket, n):
+                    spec = jax.ShapeDtypeStruct(
+                        (bucket, cfg.img_size, cfg.img_size,
+                         cfg.in_channels),
+                        jnp.float32, sharding=self._batch_sharding)
+                    exe = jax.jit(self._fwd).lower(self.model,
+                                                   spec).compile()
                 self._compiled[bucket] = exe
                 self.compile_count += 1
-                self.obs.event("compile", bucket=bucket, result="miss",
-                               compile_us=(time.perf_counter() - t0) * 1e6)
         else:
             self._m_compile_hit.inc()
         return exe
@@ -348,7 +420,7 @@ class SNNServeEngine:
     # -- main loop -----------------------------------------------------------
 
     def begin_step(self, batch: List[SNNRequest], bucket: Optional[int] = None,
-                   queue_depth: Optional[int] = None) -> InflightStep:
+                   cohort: Optional[int] = None) -> InflightStep:
         """Slot-level admission hook: dispatch one FORMED microbatch and
         return without blocking on the result.
 
@@ -360,38 +432,50 @@ class SNNServeEngine:
         The synchronous :meth:`step` simply calls the pair back to back.
 
         ``batch`` requests must already carry ``queue_s`` (enqueue ->
-        admit) and ``_t0``; ``queue_depth`` is what the admit span
-        reports as still waiting (defaults to the engine's own queue —
-        the async tier passes its own queue's depth)."""
-        n = len(batch)
-        if n == 0:
+        admit) and ``_t0``.  Records the ``fill``, ``compile`` (on a
+        miss) and ``dispatch`` phases of cohort ``cohort`` (default: a
+        fresh id).  A caller with more to do inside ``fill`` (the async
+        tier seats slots) opens that phase itself and calls
+        :meth:`fill` and :meth:`launch` in place of this."""
+        if not batch:
             raise ValueError("begin_step needs a non-empty batch")
+        if cohort is None:
+            cohort = self.new_cohort()
+        with self.phase("fill", cohort) as ph:
+            images = self.fill(batch, ph, bucket)
+        return self.launch(batch, images, cohort)
+
+    def fill(self, batch: List[SNNRequest], ph: Phase,
+             bucket: Optional[int] = None) -> np.ndarray:
+        """The cohort's padded bucket array (``bucket`` rows, default the
+        smallest that holds ``batch``), inside the open ``fill`` phase
+        ``ph``, which it tells the bucket and row count."""
+        n = len(batch)
         if bucket is None:
             bucket = self.bucket_for(n)
-        if queue_depth is None:
-            queue_depth = len(self.queue)
-        pad_frac = (bucket - n) / bucket
+        ph.bucket, ph.n = bucket, n
         self._m_occupancy.set(n / bucket)
-        self._m_pad_waste.set(pad_frac)
-        self.obs.event("admit", n=n, bucket=bucket, pad_frac=pad_frac,
-                       queue_depth=queue_depth)
-        exe = self._executable(bucket)
-
+        self._m_pad_waste.set((bucket - n) / bucket)
         images = np.zeros((bucket, self.cfg.img_size, self.cfg.img_size,
                            self.cfg.in_channels), np.float32)
         for i, req in enumerate(batch):
             images[i] = req.image
-        t0 = time.perf_counter()
-        # the annotation names this dispatch in --profile traces
-        # (snn_serve_step/b<bucket>) — zero work when nothing is tracing
-        with jax.profiler.TraceAnnotation(f"snn_serve_step/b{bucket}"):
+        return images
+
+    def launch(self, batch: List[SNNRequest], images: np.ndarray,
+               cohort: int) -> InflightStep:
+        """Transfer a filled bucket array and call its executable (the
+        ``compile`` phase on a miss, then ``dispatch``)."""
+        n, bucket = len(batch), images.shape[0]
+        exe = self._executable(bucket, cohort, n)
+        with self.phase("dispatch", cohort, bucket, n) as ph:
             if self._batch_sharding is None:
                 images = jnp.asarray(images)
             else:
                 images = jax.device_put(images, self._batch_sharding)
             logits = exe(self.model, images)
         return InflightStep(batch=batch, bucket=bucket, n=n, logits=logits,
-                            t0=t0, pad_frac=pad_frac)
+                            t0=ph.t0, cohort=cohort)
 
     def finish_step(self, st: InflightStep,
                     sink: Optional[Callable[[SNNRequest], None]] = None
@@ -400,9 +484,21 @@ class SNNServeEngine:
         completed request to ``sink`` (default: the ``done`` dict the
         synchronous ``pop_result`` drains — the async tier passes a sink
         that resolves futures instead, so ``done`` never grows there).
+        Records the ``wait``, ``readback`` and ``resolve`` phases.
         Returns the number of requests completed."""
-        logits = np.asarray(jax.block_until_ready(st.logits))
-        dt = time.perf_counter() - st.t0
+        bucket, n, cohort = st.bucket, st.n, st.cohort
+        with self.phase("wait", cohort, bucket, n):
+            jax.block_until_ready(st.logits)
+        with self.phase("readback", cohort, bucket, n):
+            logits = np.asarray(st.logits)
+        with self.phase("resolve", cohort, bucket, n):
+            self._resolve(st, logits, sink)
+        return n
+
+    def _resolve(self, st: InflightStep, logits: np.ndarray,
+                 sink: Optional[Callable[[SNNRequest], None]]) -> None:
+        now = time.perf_counter()
+        dt = now - st.t0
         bucket, n = st.bucket, st.n
         with self._acct_lock:
             self.per_bucket[bucket] = self.per_bucket.get(bucket, 0) + 1
@@ -412,10 +508,7 @@ class SNNServeEngine:
             self.total_slots += bucket
         self._m_batches.inc()
         self._m_compute_us.observe(dt * 1e6)
-        self.obs.event("step", bucket=bucket, n=n, pad_frac=st.pad_frac,
-                       compute_us=dt * 1e6)
 
-        now = time.perf_counter()
         for i, req in enumerate(st.batch):
             req.image = None        # consumed — don't retain every input
             req.logits = logits[i]
@@ -443,7 +536,6 @@ class SNNServeEngine:
             # after the drain loop: the histograms/gauges the rules read
             # already include this microbatch
             self._watchdog.check()
-        return n
 
     def step(self) -> int:
         """Serve one microbatch (up to max_batch queued requests, padded
@@ -452,13 +544,17 @@ class SNNServeEngine:
             return 0
         batch: List[SNNRequest] = []
         cap = min(self.ecfg.max_batch, self.buckets[-1])
-        t_admit = time.perf_counter()
-        while self.queue and len(batch) < cap:
-            req = self.queue.popleft()
-            req.queue_s = t_admit - req._t0
-            batch.append(req)
-        self._m_queue_depth.set(len(self.queue))
-        return self.finish_step(self.begin_step(batch))
+        with self.phase("take") as ph:
+            t_admit = time.perf_counter()
+            while self.queue and len(batch) < cap:
+                req = self.queue.popleft()
+                req.queue_s = t_admit - req._t0
+                batch.append(req)
+            self._m_queue_depth.set(len(self.queue))
+            ph.cohort, ph.bucket, ph.n = (self.new_cohort(),
+                                          self.bucket_for(len(batch)),
+                                          len(batch))
+        return self.finish_step(self.begin_step(batch, cohort=ph.cohort))
 
     def pop_result(self, uid: int) -> SNNRequest:
         """Remove and return a completed request.  Long-lived servers

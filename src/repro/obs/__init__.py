@@ -3,9 +3,11 @@
 One registry serves every runtime surface (see obs/README.md for the
 naming contract and the overhead policy):
 
-  * the serve engine (deploy/engine.py) traces requests through
-    enqueue -> admit -> compile hit/miss -> step -> drain and keeps
-    queue-depth / batch-occupancy / padding-waste gauges current;
+  * the serve engine (deploy/engine.py) records its worker's phases
+    (take -> fill -> compile -> dispatch -> wait -> readback -> resolve,
+    one span each per forward, always on), traces requests through
+    enqueue -> drain, and keeps queue-depth / batch-occupancy /
+    padding-waste gauges current;
   * the trainer (train/trainer.py) records step time, loss, grad-norm
     and lr, and exposes levanter-style per-step callback hooks;
   * the graph layer yields per-layer spike rates, saturation/reset
@@ -30,7 +32,8 @@ The LIVE plane sits on top of the same registry:
 The process default registry is DISABLED until something opts in
 (``--metrics`` on a launcher, :func:`enable_default` in code); disabled,
 every instrument is a shared no-op and the hot paths pay only an empty
-method call.  ``python -m repro.obs.validate`` schema-checks emitted
+method call; only the worker's phase spans still land in their bounded
+ring.  ``python -m repro.obs.validate`` schema-checks emitted
 JSONL artifacts (``--trace`` for Chrome trace exports).
 """
 
@@ -67,10 +70,12 @@ from repro.obs.registry import (     # noqa: F401
     FRACTION_EDGES,
     LATENCY_EDGES_US,
     NULL_INSTRUMENT,
+    PHASES,
     Counter,
     Gauge,
     Histogram,
     MetricsRegistry,
+    PhaseSpan,
     default_registry,
     disable_default,
     enable_default,

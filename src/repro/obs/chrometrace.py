@@ -1,13 +1,15 @@
 """Chrome/Perfetto ``trace_event`` export of the span ring buffer.
 
-The span ring (obs/registry.py) already records the whole request
-lifecycle — ``enqueue -> admit -> compile -> step -> drain`` — as point
-events with monotonic microsecond timestamps and carried durations
-(``queue_us``, ``compute_us``, ``latency_us``...).  This module turns
-that into the Chrome trace-event JSON format, so one
+The span ring (obs/registry.py) records each request's lifecycle
+(``enqueue -> drain``) and, in an enabled registry, the serving
+worker's phase spans (``take -> fill -> compile -> dispatch -> wait ->
+readback -> resolve``) as events with monotonic microsecond timestamps
+and carried durations (``dur_us``, ``latency_us``...).  This module
+turns that into the Chrome trace-event JSON format, so one
 ``chrome://tracing`` / Perfetto load shows the request lanes next to the
-device work (the ``--profile`` traces annotate each device dispatch as
-``snn_serve_step/b<bucket>``; both share the microsecond timebase).
+device work (the ``--profile`` traces annotate the same phases as
+``snn_serve_step/<phase>``, a dispatch as ``snn_serve_step/b<bucket>``;
+both share the microsecond timebase).
 
 Mapping (one track per surface, constant across exports so goldens pin
 it):
@@ -17,11 +19,11 @@ it):
     ``[ts - latency_us, ts]``, flow-connected (``ph: s`` at ``enqueue``,
     ``ph: f`` at ``drain``, ``id = uid``) so Chrome draws the arrow from
     the enqueue instant to the served request even across tracks.
-  * batch machinery on the **batch** track: ``admit`` instants,
-    ``compile/b<bucket>`` and ``step/b<bucket>`` duration events
-    reconstructed from their carried ``compile_us`` / ``compute_us``
-    (span timestamps are taken at completion, so the duration event
-    starts at ``ts - dur``).
+  * the worker's phases on the **batch** track: one duration event per
+    phase span, named by its phase (``dispatch/b<bucket>`` and
+    ``compile/b<bucket>`` by bucket too), reconstructed from its
+    carried ``dur_us`` (span timestamps are taken at completion, so the
+    duration event starts at ``ts - dur``).
   * trainer steps on the **train** track, per-layer attribution
     (``predicted_vs_measured``) and sampled telemetry on the **layers**
     track, watchdog trips/clears on the **watchdog** track.
@@ -45,7 +47,7 @@ import json
 import os
 from typing import Dict, Iterable, List, Optional, Union
 
-from repro.obs.registry import MetricsRegistry
+from repro.obs.registry import PHASES, MetricsRegistry
 
 # pid/tid layout — one process, one thread ("track") per surface
 PID = 1
@@ -110,17 +112,12 @@ def span_to_events(ev: dict) -> List[dict]:
             _base("s", f"req/{uid}", ts, TRACKS["requests"],
                   cat=_FLOW_CAT, id=uid),
         ]
-    if kind == "admit":
-        return [_base("i", "admit", ts, TRACKS["batch"], s="t",
-                      args=_args(ev))]
-    if kind == "compile":
-        return [_duration(f"compile/b{ev.get('bucket', '?')}", ts,
-                          ev.get("compile_us"), TRACKS["batch"],
-                          args=_args(ev, "compile_us"))]
-    if kind == "step":
-        return [_duration(f"step/b{ev.get('bucket', '?')}", ts,
-                          ev.get("compute_us"), TRACKS["batch"],
-                          args=_args(ev, "compute_us"))]
+    if kind in PHASES:
+        name = kind
+        if kind in ("dispatch", "compile"):
+            name = f"{kind}/b{ev.get('bucket', '?')}"
+        return [_duration(name, ts, ev.get("dur_us"), TRACKS["batch"],
+                          args=_args(ev, "dur_us"))]
     if kind == "drain":
         uid = ev.get("uid", -1)
         return [

@@ -34,14 +34,25 @@ span endpoint (obs/server.py) can drain incrementally
 (``spans_since(seq)``) and ``span_stats()`` can report how many events
 the ring has already dropped — a scraper that falls behind sees the gap
 instead of a silently truncated trace.
+
+Phase spans (``phase(name, t0, t1, cohort, bucket, n)``) are the one
+exception to disabled mode: the serving worker's cohort-level phases
+(:data:`PHASES`) land in a second bounded ring EVEN WHEN the registry is
+disabled — a few per forward, like the engine's always-on totals — so a
+benchmark or a flight recorder can read where the worker's time went
+without turning per-request tracing on.  ``phases(t0, t1)`` is the
+windowed read.  An enabled registry also appends each phase span to
+the span ring as an event named by its phase, so the JSONL, ``/spans``
+and Chrome-trace exporters carry them.
 """
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 # Shared default edges for request-latency-scale histograms (microseconds,
 # ~2.5x geometric steps from scheduler noise to a stuck second).
@@ -57,6 +68,30 @@ FRACTION_EDGES: Tuple[float, ...] = (
 )
 
 LabelsKey = Tuple[Tuple[str, str], ...]
+
+# The serving worker's phases (deploy/engine.py, serve_async/engine.py),
+# in loop order; ``compile`` runs only on a bucket-executable miss.
+PHASES: Tuple[str, ...] = ("take", "fill", "compile", "dispatch", "wait",
+                           "readback", "resolve")
+
+# Phase-ring capacity: set-up plus a 30 s window of the open-loop
+# benchmark cell at 2,800 requests/s (about 820 spans/s on a TPU v5e)
+# twice over; about 170 bytes a span.
+PHASE_RING = 1 << 16
+
+
+class PhaseSpan(NamedTuple):
+    """One serving-worker phase: ``phase`` ran from ``t0`` to ``t1``
+    (``time.perf_counter`` seconds) for cohort ``cohort`` (the forward
+    that caused it; -1 for none, e.g. an empty poll or a warmup
+    compile), a bucket of ``bucket`` rows of which ``n`` were real."""
+
+    phase: str
+    t0: float
+    t1: float
+    cohort: int
+    bucket: int
+    n: int
 
 
 def _labels_key(labels: Optional[Dict[str, str]]) -> LabelsKey:
@@ -222,6 +257,10 @@ class MetricsRegistry:
         self._metrics: Dict[Tuple[str, LabelsKey], object] = {}
         self._spans: deque = deque(maxlen=max_spans)
         self._span_seq = 0
+        self._phase_lock = threading.Lock()
+        self._phases: deque = deque(maxlen=PHASE_RING)
+        self._phases_dropped = 0
+        self._phases_lost_t = -math.inf   # latest start of a dropped one
         # monotonic epoch for span timestamps (perf_counter, never
         # time.time(): span deltas must survive NTP/DST wall-clock steps)
         self._t0 = time.perf_counter()
@@ -263,8 +302,9 @@ class MetricsRegistry:
     def event(self, name: str, **fields) -> None:
         """Append one span event to the ring buffer (no-op when
         disabled).  ``ts_us`` is microseconds since registry creation on
-        the monotonic clock; ``seq`` is the monotonically increasing
-        event number (1-based), the cursor ``spans_since`` drains by."""
+        the monotonic clock (a caller recording a span after the fact
+        passes its own); ``seq`` is the monotonically increasing event
+        number (1-based), the cursor ``spans_since`` drains by."""
         if not self.enabled:
             return
         ev = {"event": name,
@@ -274,6 +314,37 @@ class MetricsRegistry:
             self._span_seq += 1
             ev["seq"] = self._span_seq
             self._spans.append(ev)
+
+    def phase(self, name: str, t0: float, t1: float, cohort: int = -1,
+              bucket: int = 0, n: int = 0) -> None:
+        """Record one serving-worker phase span (see :class:`PhaseSpan`)
+        in the phase ring — enabled or not.  An enabled registry also
+        appends it to the span ring as event ``name`` stamped at its end
+        (``ts_us``) with its duration (``dur_us``)."""
+        span = PhaseSpan(name, t0, t1, cohort, bucket, n)
+        with self._phase_lock:
+            ring = self._phases
+            if len(ring) == ring.maxlen:
+                self._phases_dropped += 1
+                self._phases_lost_t = max(self._phases_lost_t, ring[0].t0)
+            ring.append(span)
+        if self.enabled:
+            self.event(name, ts_us=(t1 - self._t0) * 1e6,
+                       dur_us=(t1 - t0) * 1e6, cohort=cohort,
+                       bucket=bucket, n=n)
+
+    def phases(self, t0: float = -math.inf, t1: float = math.inf
+               ) -> Tuple[List[PhaseSpan], bool]:
+        """The phase spans that started in ``[t0, t1)``, by start, and
+        whether that is all of them: False once the ring has dropped a
+        span that started at or after ``t0``, so a reader never takes a
+        partial window for a whole one."""
+        with self._phase_lock:
+            spans = [s for s in self._phases if t0 <= s.t0 < t1]
+            complete = (self._phases_dropped == 0
+                        or self._phases_lost_t < t0)
+        spans.sort(key=lambda s: s.t0)
+        return spans, complete
 
     # -- introspection -------------------------------------------------------
 
@@ -322,7 +393,7 @@ class MetricsRegistry:
                 "spans": self.spans()}
 
     def reset(self) -> None:
-        """Zero every instrument IN PLACE and clear the span ring.
+        """Zero every instrument IN PLACE and clear both span rings.
 
         Call sites bind instrument handles at construction time (the
         engine/trainer overhead contract), so reset must NOT clear
@@ -337,6 +408,10 @@ class MetricsRegistry:
             self._spans.clear()
             self._span_seq = 0
             self._t0 = time.perf_counter()
+        with self._phase_lock:
+            self._phases.clear()
+            self._phases_dropped = 0
+            self._phases_lost_t = -math.inf
 
 
 # ---------------------------------------------------------------------------

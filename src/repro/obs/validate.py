@@ -1,7 +1,7 @@
 """CLI schema validator for emitted obs artifacts (the obs-smoke CI leg).
 
     PYTHONPATH=src python -m repro.obs.validate out.jsonl \
-        --require-spans enqueue,admit,step,drain \
+        --require-spans enqueue,fill,dispatch,wait,drain \
         --require-metrics snn_serve_requests_total,snn_layer_spike_rate
 
     PYTHONPATH=src python -m repro.obs.validate out.trace.json --trace

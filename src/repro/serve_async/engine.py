@@ -37,10 +37,14 @@ as the synchronous engine and the forward is batch-row independent, so
 a request's logits are identical whichever tier (and whichever cohort)
 served it — the parity test pins this per request at a fixed bucket.
 
-Observability rides the engine's registry: shared spans (``enqueue``,
-``admit``, ``step``, ``drain``) come from the engine hooks; the tier
-adds ``evict`` / ``recycle`` spans, the ``snn_serve_slot_occupancy``
-and ``snn_serve_inflight`` gauges, and submit/evict/cancel counters.
+Observability rides the engine's registry: the worker's phase spans,
+always recorded, each opened through ``engine.phase`` — ``take`` and
+``fill`` (slot seating plus the engine's ``fill``) by the worker loop,
+the rest by the engine's ``launch`` and ``finish_step``; the
+per-request ``enqueue`` / ``drain`` spans come from the engine too.
+The tier adds ``evict`` / ``recycle`` spans, the
+``snn_serve_slot_occupancy`` and ``snn_serve_inflight`` gauges, and
+submit/evict/cancel counters.
 The ``queue_growth`` watchdog rule works unchanged — the tier keeps
 ``snn_serve_queue_depth`` current from ITS queue.
 """
@@ -234,17 +238,19 @@ class AsyncSNNServeEngine:
         inflight: deque = deque()
         while True:
             want = min(self._cohort_cap, self.slots.free_count())
-            ready: List[QueueEntry] = []
             if want > 0:
                 # poll when a rollout is in flight (its compute is the
                 # batching window); otherwise sleep until an arrival or
                 # shutdown wakes us
                 timeout = 0.0 if inflight else self.acfg.idle_wait_s
-                ready, expired = self.queue.take(want, timeout=timeout)
-                for entry in expired:
-                    self._evict(entry)
-            if ready:
-                st = self._dispatch(ready)
+                with self.engine.phase("take") as ph:
+                    ready, expired = self.queue.take(want, timeout=timeout)
+                    for entry in expired:
+                        self._evict(entry)
+                    if ready:
+                        ph.cohort, ph.n = self.engine.new_cohort(), len(ready)
+                        ph.bucket = self.engine.bucket_for(ph.n)
+                st = self._dispatch(ready, ph.cohort) if ready else None
                 if st is not None:
                     inflight.append(st)
                     self._m_inflight.set(len(inflight))
@@ -262,25 +268,30 @@ class AsyncSNNServeEngine:
                 # yield until one drains
                 time.sleep(0.0005)
 
-    def _dispatch(self, ready: List[QueueEntry]
+    def _dispatch(self, ready: List[QueueEntry], cohort: int
                   ) -> Optional[InflightStep]:
-        t_admit = time.perf_counter()
+        """Seat ``ready`` in slots and fill its bucket array, one ``fill``
+        phase of cohort ``cohort``, then launch it on the engine."""
+        eng = self.engine
         batch: List[SNNRequest] = []
-        for entry in ready:
-            slot = self.slots.acquire(entry.req.uid)
-            if slot is None:            # lost a race to a peer worker
-                self.queue.requeue(entry)
-                continue
-            entry.slot = slot
-            entry.req.queue_s = t_admit - entry.req._t0
-            with self._lock:
-                self._pending[entry.req.uid] = entry
-            batch.append(entry.req)
-        self._m_queue_depth.set(len(self.queue))
-        self._m_slot_occ.set(self.slots.occupancy())
-        if not batch:                   # whole cohort lost the race
-            return None
-        return self.engine.begin_step(batch, queue_depth=len(self.queue))
+        with eng.phase("fill", cohort) as ph:
+            t_admit = ph.t0
+            for entry in ready:
+                slot = self.slots.acquire(entry.req.uid)
+                if slot is None:        # lost a race to a peer worker
+                    self.queue.requeue(entry)
+                    continue
+                entry.slot = slot
+                entry.req.queue_s = t_admit - entry.req._t0
+                with self._lock:
+                    self._pending[entry.req.uid] = entry
+                batch.append(entry.req)
+            self._m_queue_depth.set(len(self.queue))
+            self._m_slot_occ.set(self.slots.occupancy())
+            if not batch:               # whole cohort lost the race
+                return None
+            images = eng.fill(batch, ph)
+        return eng.launch(batch, images, cohort)
 
     def _sink(self, req: SNNRequest) -> None:
         """finish_step's per-request drain hook: resolve the future and
@@ -328,7 +339,8 @@ class AsyncSNNServeEngine:
             else:
                 live.append(entry)
         for i in range(0, len(live), self._cohort_cap):
-            st = self._dispatch(live[i:i + self._cohort_cap])
+            st = self._dispatch(live[i:i + self._cohort_cap],
+                                self.engine.new_cohort())
             self.engine.finish_step(st, sink=self._sink)
 
     # -- introspection -------------------------------------------------------

@@ -11,8 +11,8 @@ The contract under test (ISSUE 4 acceptance criteria):
   * (ISSUE 7) every served request carries the latency SPLIT
     (``queue_s`` + ``compute_s`` <= ``latency_s``), ``stats()`` reports
     padding waste exactly, and an enabled metrics registry sees the
-    full enqueue -> admit -> step -> drain trace while a disabled one
-    costs the engine nothing but no-op calls.
+    full enqueue -> fill -> dispatch -> wait -> drain trace while a
+    disabled one costs the engine nothing but no-op calls.
 """
 
 import dataclasses
@@ -465,11 +465,14 @@ def test_engine_metrics_integration(packed_model):
     events = [ev["event"] for ev in reg.spans()]
     assert events.count("enqueue") == 6
     assert events.count("drain") == 6
-    assert events.count("admit") == events.count("step") == stats["batches"]
+    for phase in ("take", "fill", "dispatch", "wait", "readback",
+                  "resolve"):
+        assert events.count(phase) == stats["batches"], phase
     assert events.count("compile") == 2    # warmup misses only
-    # the trace is ordered: every admit precedes its step
-    assert events.index("enqueue") < events.index("admit") \
-        < events.index("step") < events.index("drain")
+    # the trace is ordered: a forward's fill precedes its dispatch,
+    # which precedes its requests' drains
+    assert events.index("enqueue") < events.index("fill") \
+        < events.index("dispatch") < events.index("drain")
     # drain spans carry the split in microseconds
     drain = [ev for ev in reg.spans() if ev["event"] == "drain"]
     for ev in drain:

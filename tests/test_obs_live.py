@@ -8,8 +8,8 @@ The contract under test (ISSUE 9):
     from a background thread, and a scrape landing mid-``step()`` never
     deadlocks or 500s;
   * the Chrome trace export renders the span ring as trace_event JSON —
-    requests are flow-connected enqueue -> drain, compile/step/request
-    become duration events, and the export passes its own validator
+    requests are flow-connected enqueue -> drain, worker phases and
+    requests become duration events, and the export passes its own validator
     (and ``python -m repro.obs.validate --trace``);
   * ``AttributionExecutor`` leaves logits bit-exact while attributing
     blocked wall time per node and joining it against the roofline
@@ -48,9 +48,12 @@ def _serve_registry() -> MetricsRegistry:
     reg.gauge("snn_serve_queue_depth", "depth").set(0)
     reg.event("enqueue", uid=0, queue_depth=1)
     reg.event("enqueue", uid=1, queue_depth=2)
-    reg.event("admit", n=2, bucket=2, pad_frac=0.0, queue_depth=0)
-    reg.event("compile", bucket=2, result="miss", compile_us=1500.0)
-    reg.event("step", bucket=2, n=2, pad_frac=0.0, compute_us=800.0)
+    t = reg._t0
+    reg.phase("take", t + 0.0100, t + 0.0101, cohort=0, bucket=2, n=2)
+    reg.phase("fill", t + 0.0101, t + 0.0102, cohort=0, bucket=2, n=2)
+    reg.phase("compile", t + 0.0102, t + 0.0117, cohort=0, bucket=2, n=2)
+    reg.phase("dispatch", t + 0.0117, t + 0.0119, cohort=0, bucket=2, n=2)
+    reg.phase("wait", t + 0.0119, t + 0.0125, cohort=0, bucket=2, n=2)
     reg.event("drain", uid=0, queue_us=100.0, compute_us=800.0,
               latency_us=950.0)
     reg.event("drain", uid=1, queue_us=120.0, compute_us=800.0,
@@ -80,13 +83,13 @@ def test_server_endpoints_and_shutdown():
     hz = json.loads(body)
     assert status == 200 and "application/json" in ctype
     assert hz["status"] == "ok" and hz["queue_depth"] == 3
-    assert hz["spans"]["appended"] == 7
+    assert hz["spans"]["appended"] == 9
 
     # incremental drain: cursor in, cursor out
     _, _, body = _get(base + "/spans?since=0")
     page = json.loads(body)
     assert [ev["event"] for ev in page["spans"]][:3] == \
-        ["enqueue", "enqueue", "admit"]
+        ["enqueue", "enqueue", "take"]
     cursor = page["next_since"]
     _, _, body = _get(base + f"/spans?since={cursor}")
     assert json.loads(body)["spans"] == []
@@ -154,9 +157,16 @@ def test_chrome_trace_golden_synthetic_spans(tmp_path):
     r0 = req["request/0"]
     assert r0["dur"] == pytest.approx(950.0)
     assert r0["ts"] >= 0
-    # compile + step duration events carry their measured spans
+    # the worker's phase spans become duration events on the batch
+    # track, starting where they started
     assert req["compile/b2"]["dur"] == pytest.approx(1500.0)
-    assert req["step/b2"]["dur"] == pytest.approx(800.0)
+    assert req["dispatch/b2"]["dur"] == pytest.approx(200.0)
+    assert req["wait"]["dur"] == pytest.approx(600.0)
+    assert req["wait"]["ts"] == pytest.approx(11_900.0, abs=0.01)
+    from repro.obs.chrometrace import TRACKS
+    assert {req[k]["tid"] for k in ("take", "fill", "compile/b2",
+                                   "dispatch/b2", "wait")} \
+        == {TRACKS["batch"]}
     # flow start/finish share the binding category
     assert all(e["cat"] == "request" for e in starts + finishes)
 
