@@ -38,8 +38,8 @@ BENCH_serve.json.
 Scheduling hooks: ``step()`` is a thin composition of two slot-level
 hooks — ``begin_step(batch)`` dispatches a formed microbatch WITHOUT
 blocking (jax async dispatch; returns an :class:`InflightStep`) and
-``finish_step(st, sink=...)`` blocks, accounts, and hands each completed
-request to ``sink``.  The asynchronous continuous-batching tier
+``finish_step(st, sink=...)`` blocks, accounts, and hands the completed
+cohort to ``sink`` in one call.  The asynchronous continuous-batching tier
 (``repro.serve_async``) drives these hooks from worker threads,
 pipelining the next microbatch's host->device transfer under the
 current device step; ``close()`` gives both tiers graceful drain
@@ -63,7 +63,7 @@ another without overlap: ``take`` (the caller's pop from its queue),
 (a bucket-executable miss only), ``dispatch`` (host->device transfer
 plus the executable call), ``wait`` (``block_until_ready``),
 ``readback`` (logits to the host) and ``resolve`` (accounting and the
-per-request sinks).  Every site opens its phase with
+cohort's sink, once per cohort).  Every site opens its phase with
 :meth:`SNNServeEngine.phase`, which also runs the block under a
 ``jax.profiler.TraceAnnotation`` (:func:`annotation`), so a
 ``--profile`` trace names the host's phase on the device's clock.
@@ -478,14 +478,14 @@ class SNNServeEngine:
                             t0=ph.t0, cohort=cohort)
 
     def finish_step(self, st: InflightStep,
-                    sink: Optional[Callable[[SNNRequest], None]] = None
+                    sink: Optional[Callable[[List[SNNRequest]], None]] = None
                     ) -> int:
-        """Block on a dispatched microbatch, account it, and hand every
-        completed request to ``sink`` (default: the ``done`` dict the
-        synchronous ``pop_result`` drains — the async tier passes a sink
-        that resolves futures instead, so ``done`` never grows there).
-        Records the ``wait``, ``readback`` and ``resolve`` phases.
-        Returns the number of requests completed."""
+        """Block on a dispatched microbatch, account it, and hand the
+        whole completed cohort to ``sink`` in one call (default: the
+        ``done`` dict the synchronous ``pop_result`` drains — the async
+        tier passes a sink that resolves futures instead, so ``done``
+        never grows there).  Records the ``wait``, ``readback`` and
+        ``resolve`` phases.  Returns the number of requests completed."""
         bucket, n, cohort = st.bucket, st.n, st.cohort
         with self.phase("wait", cohort, bucket, n):
             jax.block_until_ready(st.logits)
@@ -496,42 +496,50 @@ class SNNServeEngine:
         return n
 
     def _resolve(self, st: InflightStep, logits: np.ndarray,
-                 sink: Optional[Callable[[SNNRequest], None]]) -> None:
+                 sink: Optional[Callable[[List[SNNRequest]], None]]) -> None:
+        """Resolve a finished cohort in one pass: one timestamp, one
+        argmax, one accounting round for the whole cohort; only filling
+        each request's result stays per request.  The per-request
+        histograms and ``drain`` events run only on an enabled
+        registry."""
         now = time.perf_counter()
         dt = now - st.t0
-        bucket, n = st.bucket, st.n
+        bucket, n, batch = st.bucket, st.n, st.batch
+        preds = logits[:n].argmax(axis=1).tolist()
+        lats = [now - req._t0 for req in batch]
         with self._acct_lock:
             self.per_bucket[bucket] = self.per_bucket.get(bucket, 0) + 1
             self.total_batches += 1
             self.total_compute_s += dt
             self.total_padded_slots += bucket - n
             self.total_slots += bucket
+            self.total_requests += n
+            self.total_latency_s += sum(lats)
+            self.total_queue_s += sum(req.queue_s for req in batch)
+            self.total_request_compute_s += dt * n
+            self.max_latency_s = max(self.max_latency_s, *lats)
         self._m_batches.inc()
+        self._m_requests.inc(n)
         self._m_compute_us.observe(dt * 1e6)
 
-        for i, req in enumerate(st.batch):
+        for req, row, pred, lat in zip(batch, logits, preds, lats):
             req.image = None        # consumed — don't retain every input
-            req.logits = logits[i]
-            req.pred = int(np.argmax(logits[i]))
+            req.logits = row
+            req.pred = pred
             req.compute_s = dt
-            req.latency_s = now - req._t0
-            with self._acct_lock:
-                self.total_requests += 1
-                self.total_latency_s += req.latency_s
-                self.total_queue_s += req.queue_s
-                self.total_request_compute_s += dt
-                self.max_latency_s = max(self.max_latency_s, req.latency_s)
-            self._m_requests.inc()
-            self._m_queue_us.observe(req.queue_s * 1e6)
-            self._m_latency_us.observe(req.latency_s * 1e6)
-            self.obs.event("drain", uid=req.uid,
-                           queue_us=req.queue_s * 1e6,
-                           compute_us=req.compute_s * 1e6,
-                           latency_us=req.latency_s * 1e6)
-            if sink is None:
-                self.done[req.uid] = req
-            else:
-                sink(req)
+            req.latency_s = lat
+        if self.obs.enabled:
+            for req in batch:
+                self._m_queue_us.observe(req.queue_s * 1e6)
+                self._m_latency_us.observe(req.latency_s * 1e6)
+                self.obs.event("drain", uid=req.uid,
+                               queue_us=req.queue_s * 1e6,
+                               compute_us=dt * 1e6,
+                               latency_us=req.latency_s * 1e6)
+        if sink is None:
+            self.done.update((req.uid, req) for req in batch)
+        else:
+            sink(batch)
         if self._watchdog is not None:
             # after the drain loop: the histograms/gauges the rules read
             # already include this microbatch

@@ -293,21 +293,33 @@ class AsyncSNNServeEngine:
             images = eng.fill(batch, ph)
         return eng.launch(batch, images, cohort)
 
-    def _sink(self, req: SNNRequest) -> None:
-        """finish_step's per-request drain hook: resolve the future and
-        recycle the slot — results never pile up in ``engine.done``."""
+    def _sink(self, batch: List[SNNRequest]) -> None:
+        """finish_step's drain hook for a whole cohort: one round on the
+        tier's lock and one on the slots' for the cohort, then resolve
+        each future — results never pile up in ``engine.done``.  The
+        per-request ``recycle`` events run only on an enabled registry."""
         with self._lock:
-            entry = self._pending.pop(req.uid)
-            self.completed += 1
-            self._reservoir.append((req.latency_s, req.queue_s))
-        uid, held_s = self.slots.release(entry.slot)
-        self.obs.event("recycle", slot=entry.slot, uid=uid,
-                       held_us=held_s * 1e6)
+            entries = [self._pending.pop(req.uid) for req in batch]
+            self.completed += len(batch)
+            self._reservoir.extend((req.latency_s, req.queue_s)
+                                   for req in batch)
+        released = self.slots.release_many([e.slot for e in entries])
+        if self.obs.enabled:
+            for entry, (uid, held_s) in zip(entries, released):
+                self.obs.event("recycle", slot=entry.slot, uid=uid,
+                               held_us=held_s * 1e6)
         self._m_slot_occ.set(self.slots.occupancy())
-        entry.future.resolve(AsyncResult(
-            uid=req.uid, status=STATUS_OK, logits=req.logits,
-            pred=req.pred, latency_s=req.latency_s, queue_s=req.queue_s,
-            compute_s=req.compute_s))
+        for entry, req in zip(entries, batch):
+            entry.future.resolve(AsyncResult(
+                uid=req.uid, status=STATUS_OK, logits=req.logits,
+                pred=req.pred, latency_s=req.latency_s, queue_s=req.queue_s,
+                compute_s=req.compute_s))
+        # Hand the interpreter lock to the threads these futures woke:
+        # callers in this process can then submit their next requests
+        # before the worker polls the queue for the next cohort.  Without
+        # it the worker finds the queue empty and blocks on the rollout
+        # still in flight, so only one rollout is on the device at a time.
+        time.sleep(0)
 
     def _evict(self, entry: QueueEntry) -> None:
         waited = time.perf_counter() - entry.req._t0

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 
 class SlotManager:
@@ -54,10 +54,22 @@ class SlotManager:
 
     def release(self, slot: int) -> Tuple[int, float]:
         """Free a slot; returns ``(uid, held_s)`` for the recycle span."""
+        return self.release_many((slot,))[0]
+
+    def release_many(self, slots: Iterable[int]) -> List[Tuple[int, float]]:
+        """Free a finished cohort's slots under one lock round and one
+        timestamp; returns ``(uid, held_s)`` per slot.  The free list
+        ends as ``release`` called on each slot in turn would leave it
+        (so LIFO reuse holds), and an unheld slot raises ``KeyError``
+        as there, the slots before it already freed."""
+        out = []
         with self._lock:
-            uid, t0 = self._held.pop(slot)
-            self._free.append(slot)
-            return uid, time.perf_counter() - t0
+            now = time.perf_counter()
+            for slot in slots:
+                uid, t0 = self._held.pop(slot)
+                self._free.append(slot)
+                out.append((uid, now - t0))
+        return out
 
     def occupied(self) -> int:
         with self._lock:

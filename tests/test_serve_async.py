@@ -30,6 +30,7 @@ import numpy as np
 import pytest
 
 from repro.deploy import SNNEngineConfig, SNNRequest, SNNServeEngine, deploy
+from repro.deploy.engine import InflightStep
 from repro.models import snn_cnn
 from repro.quant.formats import PrecisionConfig
 from repro.serve_async import (
@@ -131,6 +132,31 @@ def test_slot_manager_backpressure_and_recycling():
     assert sm.total_recycled == 1             # third seat on 2 slots
 
 
+@pytest.mark.parametrize("many", [False, True],
+                         ids=["release", "release_many"])
+def test_slot_manager_release_order(many):
+    """Releasing a cohort's slots in one ``release_many`` round leaves the
+    free list, and so the next acquisitions, as ``release`` on each slot
+    in turn does; an unheld slot raises in both."""
+    sm = SlotManager(4)
+    a, b, c, d = (sm.acquire(10 + i) for i in range(4))
+
+    def release(slots):
+        if many:
+            return sm.release_many(slots)
+        return [sm.release(s) for s in slots]
+
+    got = release([a, c, b])
+    assert [uid for uid, _ in got] == [10, 12, 11]
+    assert all(held > 0.0 for _, held in got)
+    assert sm.occupied() == 1 and sm.free_count() == 3
+    assert [sm.acquire(20 + i) for i in range(4)] == [b, c, a, None]
+    assert release([d]) and sm.free_count() == 1
+    with pytest.raises(KeyError):
+        release([d])
+    assert sm.free_count() == 1
+
+
 def test_future_resolves_once_first_write_wins():
     f = SNNFuture(0)
     assert not f.done()
@@ -202,6 +228,124 @@ def test_async_results_bit_exact_with_sync_engine(packed_model):
         assert r.ok
         np.testing.assert_array_equal(r.logits, ref[i].logits)
         assert r.pred == ref[i].pred
+
+
+def _cohort_logits(bucket, n_classes, seed):
+    """A bucket's logits with ties: row 0 has two equal maxima, row 1 is
+    constant — the first maximum is the prediction."""
+    lg = np.random.default_rng(seed).standard_normal(
+        (bucket, n_classes)).astype(np.float32)
+    lg[0, 1] = lg[0, -1] = lg[0].max() + 1.0
+    if bucket > 1:
+        lg[1] = 0.5
+    return lg
+
+
+def _stub_forward(eng, logits):
+    """Make ``eng.launch`` hand back ``logits`` (a host array) in place
+    of the device forward, so the resolve path sees chosen rows."""
+    def launch(batch, images, cohort):
+        return InflightStep(batch=batch, bucket=images.shape[0],
+                            n=len(batch), logits=logits,
+                            t0=time.perf_counter(), cohort=cohort)
+    eng.launch = launch
+
+
+def _totals(eng):
+    return (eng.total_requests, eng.total_batches, eng.total_slots,
+            eng.total_padded_slots, dict(eng.per_bucket))
+
+
+@pytest.mark.parametrize("sink", ["done", "futures"])
+@pytest.mark.parametrize("n", [1, 3, 4], ids=["one", "partial", "full"])
+def test_cohort_resolve_parity(packed_model, n, sink):
+    """A cohort resolved in one pass gives each request its own logits
+    row bit for bit, the row's first-maximum prediction (ties included)
+    and status ok; ``compute_s`` is the cohort's, ``latency_s`` covers
+    ``queue_s``; and the engine's totals match the same requests served
+    one cohort at a time through ``step()``."""
+    cfg = packed_model.cfg
+    bucket = 4
+    ecfg = SNNEngineConfig(max_batch=bucket, buckets=(bucket,))
+    images = _images(cfg, n, seed=8)
+    logits = _cohort_logits(bucket, cfg.n_classes, seed=n)
+
+    ref = SNNServeEngine(packed_model, ecfg)
+    _stub_forward(ref, logits)
+    for i in range(n):
+        ref.add_request(SNNRequest(uid=i, image=images[i]))
+    assert ref.step() == n and not ref.queue
+
+    eng = SNNServeEngine(packed_model, ecfg)
+    _stub_forward(eng, logits)
+    if sink == "done":
+        batch = [SNNRequest(uid=i, image=images[i]) for i in range(n)]
+        for req in batch:
+            req._t0 = time.perf_counter()
+        for req in batch:
+            req.queue_s = time.perf_counter() - req._t0
+        assert eng.finish_step(eng.begin_step(batch)) == n
+        got = [eng.pop_result(i) for i in range(n)]
+        status = ["ok"] * n
+    else:
+        aeng = AsyncSNNServeEngine(eng)          # served inline at close
+        futs = [aeng.submit(im) for im in images]
+        aeng.close(drain=True)
+        got = [f.result(timeout=0) for f in futs]
+        status = [r.status for r in got]
+        assert aeng.stats()["async"]["completed"] == n
+        assert len(aeng._reservoir) == n
+        assert aeng.slots.occupied() == 0
+    assert [r.uid for r in got] == list(range(n))
+
+    for i, r in enumerate(got):
+        np.testing.assert_array_equal(r.logits, logits[i])
+        assert r.pred == int(np.argmax(logits[i]))
+        assert type(r.pred) is int
+        assert r.latency_s >= r.queue_s >= 0.0
+    assert status == ["ok"] * n
+    assert got[0].pred == 1                      # the tie: first maximum
+    if n > 1:
+        assert got[1].pred == 0
+    assert len({r.compute_s for r in got}) == 1 and got[0].compute_s > 0
+
+    for e in (ref, eng):
+        assert _totals(e) == (n, 1, bucket, bucket - n, {bucket: 1})
+    lats = [r.latency_s for r in got]
+    assert eng.total_latency_s == pytest.approx(sum(lats))
+    assert eng.max_latency_s == max(lats)
+    assert eng.total_queue_s == pytest.approx(sum(r.queue_s for r in got))
+    assert eng.total_request_compute_s == pytest.approx(
+        n * got[0].compute_s)
+    ref_lats = [ref.pop_result(i).latency_s for i in range(n)]
+    assert ref.total_latency_s == pytest.approx(sum(ref_lats))
+    assert ref.max_latency_s == max(ref_lats)
+
+
+def test_sink_yields_once_the_cohort_is_resolved(packed_model, monkeypatch):
+    """The futures sink ends with ``time.sleep(0)``, after every future of
+    the cohort is resolved: the threads those futures woke then get the
+    interpreter lock, and can submit their next requests, before the
+    worker polls the queue for the next cohort."""
+    import repro.serve_async.engine as tier
+
+    cfg = packed_model.cfg
+    eng = SNNServeEngine(packed_model,
+                         SNNEngineConfig(max_batch=4, buckets=(4,)))
+    _stub_forward(eng, _cohort_logits(4, cfg.n_classes, seed=0))
+    aeng = AsyncSNNServeEngine(eng)
+    futs = [aeng.submit(im) for im in _images(cfg, 3, seed=9)]
+    yields = []
+    sleep = time.sleep
+
+    def recording_sleep(s):
+        if s == 0:
+            yields.append([f.done() for f in futs])
+        sleep(s)
+
+    monkeypatch.setattr(tier.time, "sleep", recording_sleep)
+    aeng.close(drain=True)                   # one cohort, served inline
+    assert yields == [[True, True, True]]
 
 
 def test_concurrent_submitters_lose_nothing(packed_model):
@@ -312,21 +456,30 @@ def test_close_without_drain_cancels_backlog(packed_model):
     assert stats["async"]["completed"] == 0
 
 
-def test_async_tier_emits_recycle_spans_and_gauges(packed_model):
+@pytest.mark.parametrize("bucket,before_start", [(2, False), (4, True)],
+                         ids=["bucket2", "bucket4-one-cohort"])
+def test_async_tier_emits_recycle_spans_and_gauges(packed_model, bucket,
+                                                   before_start):
     """With an enabled registry the tier adds evict/recycle spans and
-    slot/queue gauges on top of the engine's request trace."""
+    slot/queue gauges on top of the engine's request trace — one
+    ``recycle`` and one ``drain`` per served request, also when a cohort
+    holds several (four queued before the worker starts, bucket 4)."""
     from repro.obs import MetricsRegistry
 
     reg = MetricsRegistry()
     eng = SNNServeEngine(packed_model,
-                         SNNEngineConfig(max_batch=2, buckets=(2,)),
+                         SNNEngineConfig(max_batch=bucket, buckets=(bucket,)),
                          registry=reg)
     aeng = AsyncSNNServeEngine(eng, AsyncEngineConfig(workers=1))
     images = _images(packed_model.cfg, 4, seed=6)
     timed_out = aeng.submit(images[0], deadline_ms=1.0)
     time.sleep(0.01)
-    aeng.start()
-    futs = [aeng.submit(im) for im in images]
+    if before_start:
+        futs = [aeng.submit(im) for im in images]
+        aeng.start()
+    else:
+        aeng.start()
+        futs = [aeng.submit(im) for im in images]
     assert all(f.result(timeout=120).ok for f in futs)
     aeng.close()
     assert timed_out.result(timeout=0).status == "timeout"
@@ -334,10 +487,16 @@ def test_async_tier_emits_recycle_spans_and_gauges(packed_model):
     events = [ev["event"] for ev in reg.spans()]
     assert events.count("enqueue") == 5        # emplace-on-arrival spans
     assert events.count("recycle") == 4        # one per served request
+    assert events.count("drain") == 4
     assert events.count("evict") == 1
     recycles = [ev for ev in reg.spans() if ev["event"] == "recycle"]
+    drains = [ev for ev in reg.spans() if ev["event"] == "drain"]
     assert all(ev["held_us"] > 0 for ev in recycles)
-    assert {ev["uid"] for ev in recycles} == {f.uid for f in futs}
+    uids = sorted(f.uid for f in futs)
+    assert sorted(ev["uid"] for ev in recycles) == uids
+    assert sorted(ev["uid"] for ev in drains) == uids
+    if before_start:                           # the four shared one cohort
+        assert eng.total_batches == 1
     assert reg.counter("snn_serve_evictions_total").value == 1
     assert reg.counter("snn_serve_submitted_total").value == 5
     assert reg.gauge("snn_serve_slot_occupancy").value == 0.0  # all freed
