@@ -204,6 +204,7 @@ def spiking_conv_int_apply(
     stride: int = 1,
     threshold_q=None,
     qct=None,
+    layer: str = "",
 ):
     """Integer deployment twin of :func:`spiking_conv_apply`.
 
@@ -222,6 +223,8 @@ def spiking_conv_int_apply(
     pass the packed ``qct`` (with ``threshold_q``) instead — ``params``
     is then ignored.
 
+    ``layer`` names the layer in the kernel's lowering record.
+
     Returns (T, B, Ho, Wo, c_out) {0,1} int32 spikes (SAME padding, as
     the float path's ``_conv2d``).
     """
@@ -238,7 +241,7 @@ def spiking_conv_int_apply(
     _, packed_out = fused_conv_ops.fused_conv_rollout(
         packed_in, qct, stride=stride, padding="SAME",
         leak_shift=lif.leak_shift, threshold_q=threshold_q,
-        soft_reset=lif.soft_reset,
+        soft_reset=lif.soft_reset, layer=layer,
     )
     return packing.unpack_bool(packed_out, qct.c_out)
 
@@ -248,6 +251,7 @@ def spiking_conv_group_int_apply(
     spikes_t: jnp.ndarray,      # (T, B, H, W, C) — {0,1} binary spikes
     lif: LIFConfig,
     pc: PrecisionConfig,
+    layers=(),
 ):
     """Fusion-group twin of :func:`spiking_conv_int_apply`: a chain of
     2+ stride-1 conv layers (with optional interleaved max pools) runs
@@ -261,7 +265,8 @@ def spiking_conv_group_int_apply(
     ``threshold_q`` from a deploy package), ``("pool", window)`` entries
     the pool window.  Thresholds fold exactly as the single-layer twin;
     the chain is bit-exact with composing :func:`spiking_conv_int_apply`
-    and :func:`maxpool_t` member by member.
+    and :func:`maxpool_t` member by member.  ``layers`` names the conv
+    members in the kernels' lowering record.
 
     Returns (T, B, HoF, WoF, c_outF) {0,1} int32 spikes.
     """
@@ -288,7 +293,7 @@ def spiking_conv_group_int_apply(
     packed_in = packing.pack_bool(spikes_t.astype(jnp.int32))
     _, packed_out = fused_group_ops.fused_group_rollout(
         packed_in, tuple(chain),
-        leak_shift=lif.leak_shift, soft_reset=lif.soft_reset,
+        leak_shift=lif.leak_shift, soft_reset=lif.soft_reset, layers=layers,
     )
     return packing.unpack_bool(packed_out, last_c_out)
 

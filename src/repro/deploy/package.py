@@ -311,10 +311,13 @@ def deploy_config(model: str = "vgg9", bits: int = 4, smoke: bool = True,
                   fusion=()):
     """The int-deploy ``SNNConfig`` every serve entry point shares:
     reduced smoke geometry (CI-sized, matches the kernel test configs)
-    or the paper-size model.  Keeps the launcher, benchmark, and example
+    or the paper-size model (its input geometry from
+    ``repro.graph.build.MODEL_GEOMETRY``: CIFAR by default, ImageNet
+    for resnet50).  Keeps the launcher, benchmark, and example
     measuring the same model.  ``fusion`` is the multi-layer fusion
     request (``()`` / ``"auto"`` / explicit member tuples — see
     repro.graph.fusion)."""
+    from repro.graph.build import MODEL_GEOMETRY
     from repro.models.snn_cnn import SNNConfig
 
     fusion = _normalize_fusion(fusion)
@@ -324,7 +327,7 @@ def deploy_config(model: str = "vgg9", bits: int = 4, smoke: bool = True,
                          scale=0.15, n_classes=4, int_deploy=True,
                          precision=pc, fusion=fusion)
     return SNNConfig(model=model, int_deploy=True, precision=pc,
-                     fusion=fusion)
+                     fusion=fusion, **MODEL_GEOMETRY.get(model, {}))
 
 
 # ---------------------------------------------------------------------------

@@ -197,16 +197,18 @@ class IntExecutor(FloatExecutor):
         the SAME per-member rows the ungrouped lowering records — fusion
         changes where planes live, not which layers exist, so the
         executor-parity contract on traces is preserved."""
-        members = []
+        members, layers = [], []
         for spec in specs:
             if isinstance(spec, Conv):
                 self.trace.append(("conv", spec.name, spec.stride))
                 members.append(("conv", self._operands(spec, "qct")))
+                layers.append(spec.name)
             else:
                 self.trace.append(("pool", spec.name, 1))
                 members.append(("pool", spec.window))
         return spiking_conv_group_int_apply(members, x, self.lif,
-                                            self.cfg.precision)
+                                            self.cfg.precision,
+                                            layers=tuple(layers))
 
     def _operands(self, spec, key: str) -> dict:
         """Where the packed layer's weights come from — the one hook the
@@ -220,7 +222,8 @@ class IntExecutor(FloatExecutor):
         kw = self._operands(spec, "qct")
         return spiking_conv_int_apply(kw.pop("params"), x, self.lif,
                                       self.cfg.precision,
-                                      stride=spec.stride, **kw)
+                                      stride=spec.stride, layer=spec.name,
+                                      **kw)
 
     def _pool(self, spec, x):
         return maxpool_t(x, spec.window)

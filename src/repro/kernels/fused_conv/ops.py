@@ -15,17 +15,22 @@ columns and the padding back off (``fused_common.grid_to_nhwc``).  Zero spike
 words are inert in the accumulate and the kernel masks spikes of padded
 channels, so padding never changes the visible bits.
 
-Geometry too large for the kernel's VMEM working set (kernels/vmem.py —
-the single budget formula shared with the kernel's own check and the
-fusion planner) falls back to the unfused reference path with a
-``RuntimeWarning`` instead of emitting a kernel that cannot stay
-resident; calling kernel.py directly with such geometry raises.
+A layer whose whole output per batch element does not fit the VMEM
+budget (kernels/vmem.py — the single budget formula shared with the
+kernel's own check and the fusion planner) runs the kernel over a grid
+of output tiles (:func:`conv_tile`): blocks of c_out, and for a 1x1
+conv blocks of output pixels too.  A layer that fits keeps the untiled
+kernel.  Only geometry that no tile fits falls back to the unfused
+reference path, with a ``RuntimeWarning``, instead of emitting a kernel
+that cannot stay resident; calling kernel.py directly with such geometry
+raises.  Each call records how it lowered its layer in the process
+registry (``repro.obs.MetricsRegistry.lowered``).
 """
 
 from __future__ import annotations
 
 import warnings
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -43,6 +48,55 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def conv_tile(*, n_phase: int, pq: int, cin_pad: int, kh: int, kw: int,
+              l_out: int, n: int, bits: int, budget: int
+              ) -> Tuple[Optional[Tuple[int, int]], int]:
+    """The output tile a fused conv runs with, and its VMEM working set.
+
+    Returns ``(None, need)`` where the whole layer fits ``budget`` (the
+    untiled kernel), else the (pixels, channels) tile that fits with the
+    least repeated work: each channel tile unpacks the input plane again
+    and each pixel tile the weights, so the cost counted is
+    ``pixel tiles x kh*kw*n + channel tiles x padded input lanes``.
+    Channel tiles are 32-multiples dividing ``n``; pixel tiles, for 1x1
+    convs only (a larger kernel's block would need a halo), are
+    128-lane multiples.  Where no tile fits either, returns
+    ``(None, need)`` with the whole layer's need, over the budget.
+    """
+    geo = dict(n_phase=n_phase, pq=pq, cin_pad=cin_pad, kh=kh, kw=kw,
+               l_out=l_out, n=n, bits=bits)
+    need = _vmem.conv_rollout_vmem_bytes(**geo)
+    if need <= budget:
+        return None, need
+    rows = [32 * d for d in range(n // 32, 0, -1) if (n // 32) % d == 0]
+    pixels = [l_out]
+    if (kh, kw) == (1, 1):
+        n_r = 2
+        while pixels[-1] > 128:
+            p = _round_up(-(-l_out // n_r), 128)
+            if p < pixels[-1]:
+                pixels.append(p)
+            n_r += 1
+    best, best_cost = (None, need), None
+    for r in rows:
+        for p in pixels:
+            tile_need = _vmem.conv_rollout_vmem_bytes(**geo, tile=(p, r))
+            if tile_need > budget:
+                continue
+            n_r = -(-l_out // p)
+            lanes = n_r * p if (kh, kw) == (1, 1) else n_phase * pq
+            cost = n_r * kh * kw * n + (n // r) * lanes
+            if best_cost is None or cost < best_cost:
+                best, best_cost = ((p, r), tile_need), cost
+    return best
+
+
+def _record(layer: str, path: str, tile=None, need: int = 0) -> None:
+    from repro.obs import default_registry   # local: obs imports graph
+
+    default_registry().lowered(layer, path, tile, need)
+
+
 def fused_conv_rollout(
     spikes_packed_t: jnp.ndarray,  # (T, B, H, W, ceil(c_in/32)) int32
     qct: QuantizedConvTensor,      # packed HWIO integer codes
@@ -53,6 +107,7 @@ def fused_conv_rollout(
     threshold_q: int,
     v_reset_q: int = 0,
     soft_reset: bool = True,
+    layer: str = "",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """All T timesteps of one spiking conv layer in a single fused pass.
 
@@ -60,6 +115,8 @@ def fused_conv_rollout(
     per-output-channel int32 vector of length ``c_out`` — the per-channel
     integer threshold fold (theta_q[c] ~ theta / scale[c]) that rides as
     a row-vector operand on the kernel.
+
+    ``layer`` names the layer in the lowering record.
 
     Returns (v_T: (B, Ho, Wo, c_out) int32,
              out_spikes_packed: (T, B, Ho, Wo, ceil(c_out/32)) int32),
@@ -77,6 +134,7 @@ def fused_conv_rollout(
     theta = as_theta_vector(threshold_q, qct.c_out)
 
     if _backend.get_backend() == "jnp":
+        _record(layer, "unfused")
         return _ref.fused_conv_rollout_ref(
             spikes_packed_t, qct, stride=stride, padding=padding,
             leak_shift=leak_shift, threshold_q=theta,
@@ -97,20 +155,24 @@ def fused_conv_rollout(
                           qct.kh, qct.kw, stride, ho, wo)
 
     # explicit VMEM residency check (the budget the fusion planner and
-    # the kernel's own ValueError share): oversized geometry degrades to
-    # the bit-exact unfused reference path instead of miscompiling
-    need = _vmem.conv_rollout_vmem_bytes(
-        n_phase=stride * stride, pq=sp.shape[-1], cin_pad=qct.c_in_pad,
-        kh=qct.kh, kw=qct.kw, l_out=ho * wq, n=n_pad, bits=qct.bits)
+    # the kernel's own ValueError share): a layer too large for one block
+    # per batch element runs in output tiles, and geometry no tile fits
+    # degrades to the bit-exact unfused reference path
     budget = _vmem.vmem_budget_bytes()
+    tile, need = conv_tile(
+        n_phase=stride * stride, pq=sp.shape[-1], cin_pad=qct.c_in_pad,
+        kh=qct.kh, kw=qct.kw, l_out=ho * wq, n=n_pad, bits=qct.bits,
+        budget=budget)
     if need > budget:
         warnings.warn(
             f"fused_conv geometry (plane {h}x{w}x{qct.c_in_pad}, "
             f"out {ho}x{wo}x{n_pad}, stride {stride}, w{qct.bits}) needs "
             f"~{_vmem.format_bytes(need)} of VMEM > budget "
-            f"{_vmem.format_bytes(budget)}; falling back to the unfused "
-            f"reference path (bit-exact, but per-timestep HBM traffic)",
+            f"{_vmem.format_bytes(budget)}, and no output tile of it "
+            f"fits; falling back to the unfused reference path "
+            f"(bit-exact, but per-timestep HBM traffic)",
             RuntimeWarning, stacklevel=2)
+        _record(layer, "unfused")
         return _ref.fused_conv_rollout_ref(
             spikes_packed_t, qct, stride=stride, padding=padding,
             leak_shift=leak_shift, threshold_q=theta,
@@ -126,8 +188,9 @@ def fused_conv_rollout(
         bits=qct.bits, kh=qct.kh, kw=qct.kw, cin_pad=qct.c_in_pad,
         stride=stride, ho=ho, wo=wo, wq=wq, n_out=qct.c_out,
         leak_shift=leak_shift,
-        v_reset_q=v_reset_q, soft_reset=soft_reset,
+        v_reset_q=v_reset_q, soft_reset=soft_reset, tile=tile,
         interpret=(_backend.get_backend() == "interpret"),
     )
+    _record(layer, "fused" if tile is None else "fused_tiled", tile, need)
     return (_fc.grid_to_nhwc(v, ho, wq, wo, qct.c_out),
             _fc.grid_to_nhwc(out, ho, wq, wo, words_out))

@@ -44,6 +44,14 @@ def _round32(x: int) -> int:
     return -(-x // 32) * 32
 
 
+def _record(layers: Sequence[str], path: str, need: int = 0) -> None:
+    """Each conv member's lowering record (one group estimate for all)."""
+    from repro.obs import default_registry   # local: obs imports graph
+
+    for layer in layers:
+        default_registry().lowered(layer, path, None, need)
+
+
 def _normalize_members(members: Sequence[Tuple], h: int, w: int,
                        win: int) -> Tuple[Tuple, ...]:
     """Validate the chain and normalize thresholds to (c_out,) vectors.
@@ -141,6 +149,7 @@ def fused_group_rollout(
     leak_shift: int,
     v_reset_q: int = 0,
     soft_reset: bool = True,
+    layers: Sequence[str] = (),
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """All T timesteps of a whole fusion-group chain in one fused pass.
 
@@ -148,6 +157,7 @@ def fused_group_rollout(
     final membrane, pre-pool if a pool ends the chain — and
     out_spikes_packed: (T, B, HoF, WoF, ceil(c_outF/32)) int32), bit-
     exact with the per-layer fused_conv composition of ref.py.
+    ``layers`` names the conv members in the lowering record.
     """
     t_steps, b, h, w, win = spikes_packed_t.shape
     members = _normalize_members(members, h, w, win)
@@ -155,6 +165,7 @@ def fused_group_rollout(
     last_qct = convs[-1][1]
 
     if _backend.get_backend() == "jnp":
+        _record(layers, "unfused")
         return _ref.fused_group_rollout_ref(
             spikes_packed_t, members, leak_shift=leak_shift,
             v_reset_q=v_reset_q, soft_reset=soft_reset)
@@ -183,6 +194,7 @@ def fused_group_rollout(
             f"to the per-layer reference path (bit-exact, but inter-"
             f"member planes round-trip HBM)",
             RuntimeWarning, stacklevel=2)
+        _record(layers, "unfused")
         return _ref.fused_group_rollout_ref(
             spikes_packed_t, members, leak_shift=leak_shift,
             v_reset_q=v_reset_q, soft_reset=soft_reset)
@@ -209,6 +221,7 @@ def fused_group_rollout(
         v_reset_q=v_reset_q, soft_reset=soft_reset,
         interpret=(_backend.get_backend() == "interpret"))
 
+    _record(layers, "fused_group", need)
     grid_f = _kernel.chain_grids(geoms)[-1][1]
     v = _fc.grid_to_nhwc(v, h_lc, w_lc + k - 1, w_lc, last_qct.c_out)
     out = _fc.grid_to_nhwc(out, hf, grid_f, wf, words_out)

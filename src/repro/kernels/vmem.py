@@ -33,7 +33,7 @@ experiments or tests.
 from __future__ import annotations
 
 import os
-from typing import Dict, Sequence
+from typing import Dict, Optional, Sequence, Tuple
 
 # default scoped VMEM limit of a Mosaic kernel on a TPU v5e core; the
 # budget keeps headroom under it for the compiler's internal scratch
@@ -88,7 +88,8 @@ def _conv_core_bytes(*, pq: int, cin_pad: int, kh: int, kw: int,
 
 def conv_rollout_vmem_bytes(*, n_phase: int, pq: int, cin_pad: int,
                             kh: int, kw: int, l_out: int, n: int,
-                            bits: int) -> int:
+                            bits: int, tile: Optional[Tuple[int, int]] = None
+                            ) -> int:
     """VMEM working set of one fused conv rollout step, per batch
     element, from static geometry.
 
@@ -97,7 +98,20 @@ def conv_rollout_vmem_bytes(*, n_phase: int, pq: int, cin_pad: int,
     32-padded input channel count, ``l_out`` the flattened output grid
     (Ho * wq), ``n`` the 32-padded c_out and ``bits`` the weight
     precision.
+
+    ``tile`` = (pixels, channels) counts one output block of a tiled
+    rollout (kernels/fused_conv ``tile``) instead of the whole layer:
+    ``channels`` of the c_out and ``pixels`` lanes of the flattened
+    output grid.  A tiled 1x1 conv reads the same pixels of its one
+    phase plane; a larger kernel reads the whole plane (a block of fewer
+    pixels would need a halo).
     """
+    if tile is not None:
+        pixels, n = tile
+        if (kh, kw) == (1, 1):
+            n_phase, pq, l_out = 1, pixels, pixels
+        elif pixels != l_out:
+            raise ValueError(f"a {kh}x{kw} conv tiles channels only")
     plane_block = 2 * n_phase * _rows(cin_pad // 32) * _lanes(pq) * 4
     return (plane_block
             + (n_phase - 1) * 2 * _lanes(pq) * cin_pad * 5

@@ -36,7 +36,7 @@ def main():
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="vgg9",
-                    choices=("vgg9", "vgg16", "resnet18"))
+                    choices=("vgg9", "vgg16", "resnet18", "resnet50"))
     ap.add_argument("--bits", type=int, default=4, choices=(2, 4, 8))
     add_geometry_flags(ap)
     ap.add_argument("--requests", type=int, default=32)

@@ -1,4 +1,5 @@
-"""The paper's benchmark models: VGG-16-SNN and ResNet-18-SNN.
+"""The paper's benchmark models: VGG-16-SNN and ResNet-18-SNN, and
+ResNet-50-SNN at ImageNet geometry.
 
 These are the networks behind L-SPINE's §III-D comparison (VGG-16:
 CPU 23.97 s vs engine 4.83 ms INT2 / 16.94 ms INT8; ResNet-18: 34.43 s
@@ -54,7 +55,7 @@ from repro.quant.formats import PrecisionConfig
 
 @dataclasses.dataclass(frozen=True)
 class SNNConfig:
-    model: str = "vgg16"          # vgg16 | vgg9 | resnet18
+    model: str = "vgg16"          # vgg16 | vgg9 | resnet18 | resnet50
     n_classes: int = 10
     in_channels: int = 3
     img_size: int = 32

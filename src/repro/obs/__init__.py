@@ -33,7 +33,8 @@ The process default registry is DISABLED until something opts in
 (``--metrics`` on a launcher, :func:`enable_default` in code); disabled,
 every instrument is a shared no-op and the hot paths pay only an empty
 method call; only the worker's phase spans still land in their bounded
-ring.  ``python -m repro.obs.validate`` schema-checks emitted
+ring, and each packed conv layer still leaves its lowering record.
+``python -m repro.obs.validate`` schema-checks emitted
 JSONL artifacts (``--trace`` for Chrome trace exports).
 """
 
@@ -69,11 +70,13 @@ from repro.obs.watchdog import (     # noqa: F401
 from repro.obs.registry import (     # noqa: F401
     FRACTION_EDGES,
     LATENCY_EDGES_US,
+    LOWERING_PATHS,
     NULL_INSTRUMENT,
     PHASES,
     Counter,
     Gauge,
     Histogram,
+    Lowering,
     MetricsRegistry,
     PhaseSpan,
     default_registry,

@@ -44,6 +44,12 @@ without turning per-request tracing on.  ``phases(t0, t1)`` is the
 windowed read.  An enabled registry also appends each phase span to
 the span ring as an event named by its phase, so the JSONL, ``/spans``
 and Chrome-trace exporters carry them.
+
+Lowering records (``lowered(layer, path, tile, vmem_bytes)``) are always
+on too: each packed conv layer, when it is traced, records which path
+the kernel dispatch took for it (:data:`LOWERING_PATHS`), its output
+tile and the VMEM working set it was budgeted, one record per layer
+name, the latest lowering winning.  ``lowerings()`` reads them.
 """
 
 from __future__ import annotations
@@ -78,6 +84,26 @@ PHASES: Tuple[str, ...] = ("take", "fill", "compile", "dispatch", "wait",
 # benchmark cell at 2,800 requests/s (about 820 spans/s on a TPU v5e)
 # twice over; about 170 bytes a span.
 PHASE_RING = 1 << 16
+
+
+# How a packed conv layer was lowered: its own fused_conv kernel (whole
+# layer per batch element, or a grid of output tiles), a member of a
+# fused_group chain, or the unfused reference scan (a VMEM fallback, or
+# the jnp backend).
+LOWERING_PATHS: Tuple[str, ...] = ("fused", "fused_tiled", "fused_group",
+                                   "unfused")
+
+
+class Lowering(NamedTuple):
+    """How layer ``layer`` was lowered: ``path`` (one of
+    :data:`LOWERING_PATHS`), its output ``tile`` of (pixels, channels)
+    where the path tiles (else None), and the VMEM working set in bytes
+    that the dispatch budgeted for it (kernels/vmem.py; 0 unfused)."""
+
+    layer: str
+    path: str
+    tile: Optional[Tuple[int, int]]
+    vmem_bytes: int
 
 
 class PhaseSpan(NamedTuple):
@@ -261,6 +287,7 @@ class MetricsRegistry:
         self._phases: deque = deque(maxlen=PHASE_RING)
         self._phases_dropped = 0
         self._phases_lost_t = -math.inf   # latest start of a dropped one
+        self._lowerings: Dict[str, Lowering] = {}
         # monotonic epoch for span timestamps (perf_counter, never
         # time.time(): span deltas must survive NTP/DST wall-clock steps)
         self._t0 = time.perf_counter()
@@ -346,6 +373,25 @@ class MetricsRegistry:
         spans.sort(key=lambda s: s.t0)
         return spans, complete
 
+    # -- lowering records ----------------------------------------------------
+
+    def lowered(self, layer: str, path: str,
+                tile: Optional[Tuple[int, int]] = None,
+                vmem_bytes: int = 0) -> None:
+        """Record how ``layer`` was lowered (see :class:`Lowering`) —
+        enabled or not."""
+        if path not in LOWERING_PATHS:
+            raise ValueError(f"unknown lowering path {path!r}")
+        rec = Lowering(layer, path, tile, int(vmem_bytes))
+        with self._phase_lock:
+            self._lowerings.pop(layer, None)
+            self._lowerings[layer] = rec
+
+    def lowerings(self) -> List[Lowering]:
+        """The lowering records, oldest first."""
+        with self._phase_lock:
+            return list(self._lowerings.values())
+
     # -- introspection -------------------------------------------------------
 
     def metrics(self) -> List[object]:
@@ -393,7 +439,8 @@ class MetricsRegistry:
                 "spans": self.spans()}
 
     def reset(self) -> None:
-        """Zero every instrument IN PLACE and clear both span rings.
+        """Zero every instrument IN PLACE and clear both span rings and
+        the lowering records.
 
         Call sites bind instrument handles at construction time (the
         engine/trainer overhead contract), so reset must NOT clear
@@ -412,6 +459,7 @@ class MetricsRegistry:
             self._phases.clear()
             self._phases_dropped = 0
             self._phases_lost_t = -math.inf
+            self._lowerings.clear()
 
 
 # ---------------------------------------------------------------------------
