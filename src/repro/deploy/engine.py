@@ -39,7 +39,14 @@ Scheduling hooks: ``step()`` is a thin composition of two slot-level
 hooks — ``begin_step(batch)`` dispatches a formed microbatch WITHOUT
 blocking (jax async dispatch; returns an :class:`InflightStep`) and
 ``finish_step(st, sink=...)`` blocks, accounts, and hands the completed
-cohort to ``sink`` in one call.  The asynchronous continuous-batching tier
+cohort to ``sink`` in one call.  The host bucket array a forward reads
+is allocated once per bucket and in-flight forward, then recycled:
+``fill`` takes one from the bucket's free list (a miss allocates) and
+``finish_step`` returns it once the forward that read it has finished —
+only then has the transfer released it (on the CPU backend the device
+array may alias it outright).  A fresh array per forward would fault in
+every page where it is past glibc's mmap threshold: 38.5 MB at bucket
+64 of 224x224x3 images.  The asynchronous continuous-batching tier
 (``repro.serve_async``) drives these hooks from worker threads,
 pipelining the next microbatch's host->device transfer under the
 current device step; ``close()`` gives both tiers graceful drain
@@ -47,7 +54,8 @@ semantics (flush the partial bucket, then refuse new work).
 
 Observability: the engine binds instruments from a
 :class:`repro.obs.MetricsRegistry` (the process default unless one is
-passed) at construction — request/batch/compile-hit/miss counters,
+passed) at construction — request/batch/compile-hit/miss and
+fill-buffer hit/miss counters,
 queue-depth / batch-occupancy / padding-waste gauges, queue/compute/
 latency histograms, and per-request ``enqueue`` / ``drain`` span
 events.  With the default registry disabled (the default) every
@@ -59,9 +67,9 @@ The worker's phases are recorded whatever the registry's state: one
 the cohort that caused it (``new_cohort``, drawn when a take yields a
 cohort; see ``MetricsRegistry.phase``).  In loop order they follow one
 another without overlap: ``take`` (the caller's pop from its queue),
-``fill`` (seating the cohort and filling the bucket array), ``compile``
-(a bucket-executable miss only), ``dispatch`` (host->device transfer
-plus the executable call), ``wait`` (``block_until_ready``),
+``fill`` (seating the cohort and filling its recycled bucket array),
+``compile`` (a bucket-executable miss only), ``dispatch`` (host->device
+transfer plus the executable call), ``wait`` (``block_until_ready``),
 ``readback`` (logits to the host) and ``resolve`` (accounting and the
 cohort's sink, once per cohort).  Every site opens its phase with
 :meth:`SNNServeEngine.phase`, which also runs the block under a
@@ -158,6 +166,8 @@ class InflightStep:
     logits: object                  # un-materialized device array
     t0: float                       # perf_counter at dispatch
     cohort: int                     # id of the phase spans it caused
+    # the host bucket array the forward reads; finish_step recycles it
+    images: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -206,6 +216,12 @@ class SNNServeEngine:
         # one microbatch stays lock-free.
         self._compile_lock = threading.Lock()
         self._acct_lock = threading.Lock()
+        # bucket -> host bucket arrays free for the next fill, and the
+        # hit/miss counts (a miss allocates one), under one lock
+        self._fill_lock = threading.Lock()
+        self._fill_free: Dict[int, List[np.ndarray]] = {}
+        self.fill_buffer_hits = 0
+        self.fill_buffers_allocated: Dict[int, int] = {}
 
         self._mesh = None
         self._batch_sharding = None
@@ -260,6 +276,12 @@ class SNNServeEngine:
         self._m_compile_hit = m.counter("snn_serve_compile_total",
                                         "bucket executable cache hits",
                                         labels={"result": "hit"})
+        self._m_fill_miss = m.counter("snn_serve_fill_buffer_total",
+                                      "host bucket arrays allocated",
+                                      labels={"result": "miss"})
+        self._m_fill_hit = m.counter("snn_serve_fill_buffer_total",
+                                     "host bucket arrays recycled",
+                                     labels={"result": "hit"})
         self._m_queue_depth = m.gauge("snn_serve_queue_depth",
                                       "requests waiting for a batch")
         self._m_occupancy = m.gauge("snn_serve_batch_occupancy",
@@ -449,33 +471,50 @@ class SNNServeEngine:
              bucket: Optional[int] = None) -> np.ndarray:
         """The cohort's padded bucket array (``bucket`` rows, default the
         smallest that holds ``batch``), inside the open ``fill`` phase
-        ``ph``, which it tells the bucket and row count."""
+        ``ph``, which it tells the bucket and row count.  The array comes
+        from the bucket's free list (allocated on a miss) and belongs to
+        the forward until :meth:`finish_step` recycles it: hand it to
+        :meth:`launch` and nowhere else.  One copy writes the cohort's
+        rows; the padding rows are zeroed."""
         n = len(batch)
         if bucket is None:
             bucket = self.bucket_for(n)
         ph.bucket, ph.n = bucket, n
         self._m_occupancy.set(n / bucket)
         self._m_pad_waste.set((bucket - n) / bucket)
-        images = np.zeros((bucket, self.cfg.img_size, self.cfg.img_size,
-                           self.cfg.in_channels), np.float32)
-        for i, req in enumerate(batch):
-            images[i] = req.image
+        with self._fill_lock:
+            free = self._fill_free.get(bucket)
+            images = free.pop() if free else None
+            if images is None:
+                self.fill_buffers_allocated[bucket] = \
+                    self.fill_buffers_allocated.get(bucket, 0) + 1
+                self._m_fill_miss.inc()
+            else:
+                self.fill_buffer_hits += 1
+                self._m_fill_hit.inc()
+        if images is None:
+            cfg = self.cfg
+            images = np.empty((bucket, cfg.img_size, cfg.img_size,
+                               cfg.in_channels), np.float32)
+        np.stack([req.image for req in batch], out=images[:n])
+        images[n:] = 0
         return images
 
     def launch(self, batch: List[SNNRequest], images: np.ndarray,
                cohort: int) -> InflightStep:
-        """Transfer a filled bucket array and call its executable (the
-        ``compile`` phase on a miss, then ``dispatch``)."""
+        """Transfer a bucket array from :meth:`fill` and call its
+        executable (the ``compile`` phase on a miss, then ``dispatch``).
+        The returned step holds the array until :meth:`finish_step`."""
         n, bucket = len(batch), images.shape[0]
         exe = self._executable(bucket, cohort, n)
         with self.phase("dispatch", cohort, bucket, n) as ph:
             if self._batch_sharding is None:
-                images = jnp.asarray(images)
+                x = jnp.asarray(images)
             else:
-                images = jax.device_put(images, self._batch_sharding)
-            logits = exe(self.model, images)
+                x = jax.device_put(images, self._batch_sharding)
+            logits = exe(self.model, x)
         return InflightStep(batch=batch, bucket=bucket, n=n, logits=logits,
-                            t0=ph.t0, cohort=cohort)
+                            t0=ph.t0, cohort=cohort, images=images)
 
     def finish_step(self, st: InflightStep,
                     sink: Optional[Callable[[List[SNNRequest]], None]] = None
@@ -485,10 +524,16 @@ class SNNServeEngine:
         ``done`` dict the synchronous ``pop_result`` drains — the async
         tier passes a sink that resolves futures instead, so ``done``
         never grows there).  Records the ``wait``, ``readback`` and
-        ``resolve`` phases.  Returns the number of requests completed."""
+        ``resolve`` phases.  Returns the number of requests completed.
+        The step's bucket array goes back on its free list once the
+        forward has finished; a step that raises before then drops it."""
         bucket, n, cohort = st.bucket, st.n, st.cohort
         with self.phase("wait", cohort, bucket, n):
             jax.block_until_ready(st.logits)
+        if st.images is not None:
+            with self._fill_lock:
+                self._fill_free.setdefault(bucket, []).append(st.images)
+            st.images = None
         with self.phase("readback", cohort, bucket, n):
             logits = np.asarray(st.logits)
         with self.phase("resolve", cohort, bucket, n):
@@ -645,6 +690,13 @@ class SNNServeEngine:
             "compiles": self.compile_count,
             "buckets": {str(k): v
                         for k, v in sorted(self.per_bucket.items())},
+            # host bucket arrays: fills that reused one, and the arrays
+            # allocated per bucket (at most one per forward in flight)
+            "fill_buffers": {
+                "hits": self.fill_buffer_hits,
+                "allocated": {str(k): v for k, v in
+                              sorted(self.fill_buffers_allocated.items())},
+            },
             "wall_s": wall,
             "images_per_s": n / max(wall, 1e-9),
             "latency_avg_ms": 1e3 * self.total_latency_s / n if n else 0.0,
