@@ -243,5 +243,27 @@ def test_data_parallel_snn_engine_matches_single_device():
         assert all(s.data.shape[0] == 2 for s in shards), shards
         eng4.finish_step(st)
         assert eng4.compile_count == n_compiled
+
+        def batch(images, uid0):
+            reqs = [SNNRequest(uid=uid0 + i, image=im)
+                    for i, im in enumerate(images)]
+            for r in reqs:
+                r.queue_s, r._t0 = 0.0, time.perf_counter()
+            return reqs
+
+        # two cohorts in flight hold distinct host bucket arrays, each
+        # answered as on one device
+        st_a = eng4.begin_step(batch(imgs, 100), bucket=8)
+        st_b = eng4.begin_step(batch(imgs[::-1], 200), bucket=8)
+        assert st_a.images is not st_b.images
+        eng4.finish_step(st_a)
+        eng4.finish_step(st_b)
+        for uid0, want in ((100, one), (200, one[::-1])):
+            np.testing.assert_array_equal(
+                np.stack([eng4.pop_result(uid0 + i).logits
+                          for i in range(len(imgs))]), want)
+        # one array per forward in flight; the other fills reused one
+        assert eng4.stats()["fill_buffers"] == {
+            "hits": 2, "allocated": {"8": 2}}, eng4.stats()["fill_buffers"]
         print("data-parallel engine OK")
     """, devices=4))

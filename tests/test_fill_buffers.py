@@ -12,17 +12,14 @@ The contract under test:
   * misses stay at or below ``max_inflight x workers`` per bucket, every
     other fill hits, and both counts reach ``stats()`` (engine and async
     tier) and the registry's ``snn_serve_fill_buffer_total``;
-  * the synchronous ``step()`` path and a data-parallel engine on four
-    CPU devices answer as before;
+  * the synchronous ``step()`` path answers as before (the
+    data-parallel engine's arrays are checked in
+    ``test_distributed.py``);
   * a step that raises before ``finish_step`` drops its array, and
     threads sharing the free lists never hand one array to two steps.
 """
 
-import json
-import os
-import subprocess
 import sys
-import textwrap
 import threading
 import time
 
@@ -194,66 +191,6 @@ def test_sync_step_path_answers_and_recycles(packed_model):
     stats = eng.stats()
     assert stats["fill_buffers"] == {"hits": 3,
                                      "allocated": {"2": 1, "4": 1}}
-
-
-def test_data_parallel_engine_answers_unchanged():
-    """Four CPU devices: cohorts of 8 and 5 in flight together, then
-    one in the recycled array, each matches its images in a fresh
-    zeroed array split over the devices."""
-    code = textwrap.dedent("""
-        import json
-        import time
-        import jax, numpy as np
-        from repro.deploy import (SNNEngineConfig, SNNRequest,
-                                  SNNServeEngine, deploy, deploy_config)
-        from repro.models import snn_cnn
-
-        cfg = deploy_config("vgg9", bits=4, smoke=True)
-        pkg = deploy(snn_cnn.init(jax.random.PRNGKey(0), cfg), cfg)
-        eng = SNNServeEngine(pkg, SNNEngineConfig(max_batch=8,
-                                                  buckets=(8,),
-                                                  data_parallel=True))
-        rng = np.random.default_rng(0)
-        shape = (cfg.img_size, cfg.img_size, cfg.in_channels)
-        cohorts = [rng.random((n,) + shape).astype(np.float32)
-                   for n in (8, 5, 8)]
-
-        def batch(images, uid0):
-            reqs = [SNNRequest(uid=uid0 + i, image=im)
-                    for i, im in enumerate(images)]
-            for r in reqs:
-                r._t0 = time.perf_counter()
-            return reqs
-
-        st0 = eng.begin_step(batch(cohorts[0], 0))
-        st1 = eng.begin_step(batch(cohorts[1], 100))
-        distinct = st0.images is not st1.images
-        eng.finish_step(st0)
-        eng.finish_step(eng.begin_step(batch(cohorts[2], 200)))
-        eng.finish_step(st1)
-        exe = eng._executable(8)
-        same = []
-        for k, images in enumerate(cohorts):
-            ref = np.zeros((8,) + shape, np.float32)
-            ref[:len(images)] = images
-            want = np.asarray(exe(eng.model, jax.device_put(
-                ref, eng._batch_sharding)))[:len(images)]
-            got = np.stack([eng.pop_result(100 * k + i).logits
-                            for i in range(len(images))])
-            same.append(bool(np.array_equal(got, want)))
-        print(json.dumps({"devices": len(jax.devices()),
-                          "distinct": distinct, "same": same,
-                          "fill_buffers": eng.stats()["fill_buffers"]}))
-    """)
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                          text=True, timeout=420, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out["devices"] == 4 and out["distinct"]
-    assert out["same"] == [True, True, True]
-    assert out["fill_buffers"] == {"hits": 1, "allocated": {"8": 2}}
 
 
 def test_step_that_raises_drops_its_array(packed_model):
